@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.common import Recorder, finish
+from repro.launch.mesh import make_mesh
 from repro.store import engine as engine_mod
 from repro.store import exec as exec_
 from repro.store import resilience as R
@@ -43,8 +44,7 @@ WARMUP = 1
 
 
 def _fresh_engine(exec_mode=None):
-    mesh = jax.make_mesh((1,), ("local",),
-                         devices=np.array(jax.devices()[:1]))
+    mesh = make_mesh((1,), ("local",), devices=jax.devices()[:1])
     return engine_mod.StoreEngine(mesh, ("local",), LANES, backend=BACKEND,
                                   pool_factor=1, exec_mode=exec_mode)
 

@@ -17,6 +17,8 @@ def main() -> None:
     sys.path.insert(0, os.path.join(ROOT, "src"))
     sys.path.insert(0, ROOT)       # `python benchmarks/run.py` from anywhere
     import repro  # noqa: F401
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=None,
                     help="directory for BENCH_*.json artifacts")

@@ -26,6 +26,8 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 import repro  # noqa: F401,E402
+from repro.launch.cache import use_compile_cache  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.store import OP_DELETE, OP_FIND, OP_INSERT, OP_POPK  # noqa: E402
 from repro.store.engine import StoreEngine  # noqa: E402
 
@@ -54,7 +56,7 @@ def workload(n_rounds: int, total: int, seed: int = 0):
 
 
 def run_backend(name: str, rounds, exec_mode: str | None = None) -> list:
-    mesh = jax.make_mesh((2, 4), AXES)
+    mesh = make_mesh((2, 4), AXES)
     eng = StoreEngine(mesh, AXES, LANES, backend=name, pool_factor=4,
                       exec_mode=exec_mode)
     state = jax.device_put(eng.init(4096), eng.sharding)
@@ -85,6 +87,7 @@ def run_backend(name: str, rounds, exec_mode: str | None = None) -> list:
 
 
 def main():
+    use_compile_cache()
     backends = tuple(sys.argv[1:]) or BACKENDS
     rounds = workload(n_rounds=3, total=8 * LANES)
     results = {}
@@ -125,7 +128,7 @@ def demo_pq_drain():
     each shard's k smallest keys in one dispatch. Drains the store to
     empty and checks each shard's pop stream comes out sorted."""
     print("backend: pq (sharded bulk-pop-k drain)")
-    mesh = jax.make_mesh((2, 4), AXES)
+    mesh = make_mesh((2, 4), AXES)
     eng = StoreEngine(mesh, AXES, LANES, backend="pq", pool_factor=4)
     state = jax.device_put(eng.init(4096), eng.sharding)
     put = lambda x: jax.device_put(jnp.asarray(x), eng.sharding)

@@ -12,9 +12,11 @@ from repro.core.det_skiplist import (delete_batch, find_batch, insert_batch,
 from repro.core.ringqueue import pop_batch, push_batch, queue_init
 from repro.core.splitorder import (splitorder_find, splitorder_init,
                                    splitorder_insert)
+from repro.launch.cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     print("== deterministic 1-2-3-4 skiplist (paper §II) ==")
     s = skiplist_init(capacity=1024)
     keys = jnp.asarray(np.random.default_rng(0).integers(1, 10_000, 200,

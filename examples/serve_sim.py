@@ -10,11 +10,13 @@ import jax
 
 import repro  # noqa: F401
 from repro.configs import get_reduced
+from repro.launch.cache import use_compile_cache
 from repro.models import model as M
 from repro.serving.engine import Engine, Request
 
 
 def main():
+    use_compile_cache()
     cfg = get_reduced("qwen3-1.7b")
     params = M.init_params(jax.random.PRNGKey(0), cfg)
     eng = Engine(cfg, params, max_reqs=4, num_pages=64, page_size=8,

@@ -8,10 +8,12 @@ import argparse
 import repro  # noqa: F401
 from repro.configs import get_config, get_reduced
 from repro.configs.base import ShapeConfig
+from repro.launch.cache import use_compile_cache
 from repro.train.loop import train
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--small", action="store_true",

@@ -79,48 +79,11 @@ def _a2a(x: jnp.ndarray, name: str) -> jnp.ndarray:
     return jax.lax.all_to_all(x, name, 0, 0, tiled=False)
 
 
-def mesh_shard_map(f, mesh, in_specs, out_specs, axis_names=None,
-                   check_vma=None):
-    """`jax.shard_map` compat across the 0.4.x -> 0.5+ API change.
-
-    New jax: top-level `jax.shard_map(..., axis_names=..., check_vma=...)`.
-    Old jax: `jax.experimental.shard_map.shard_map(..., auto=..., check_rep=...)`
-    where `auto` is the COMPLEMENT of axis_names and check_rep ~ check_vma.
-    """
-    try:
-        from jax import shard_map as _sm
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-        kw = {}
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        if axis_names is not None:
-            auto = frozenset(mesh.axis_names) - set(axis_names)
-            if auto:
-                kw["auto"] = auto
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
-def axis_size(name: str) -> int:
-    """Static mesh-axis size inside shard_map — `jax.lax.axis_size` compat
-    (jax <= 0.4.x has no lax.axis_size; there, core.axis_frame(name) IS the
-    size)."""
-    try:
-        return jax.lax.axis_size(name)
-    except AttributeError:
-        return jax.core.axis_frame(name)
-
-
 def shard_linear_id(axis_names: Sequence[str]) -> jnp.ndarray:
     """Flat shard id over the routing axes (row-major, coarsest first)."""
     idx = jnp.int32(0)
     for name in axis_names:
-        idx = idx * axis_size(name) + jax.lax.axis_index(name).astype(jnp.int32)
+        idx = idx * jax.lax.axis_size(name) + jax.lax.axis_index(name).astype(jnp.int32)
     return idx
 
 

@@ -27,9 +27,13 @@ tier_find         fused tier-stack FIND: hot bucket probe + warm level
                   bodies shared with hash_probe / skiplist_search
 tier_apply        fused tier-stack APPLY prologue: the tier_find probes
                   + the hot-insert linearization and eviction-policy
-                  victim selection in ONE dispatch, with the spill tier
-                  streamed through VMEM chunks under a scalar-prefetched
-                  `run_offsets` plane (`pltpu.PrefetchScalarGridSpec`)
+                  victim selection in ONE dispatch, lanes scanned in
+                  sorted order with the reference's cumsums as running
+                  counts
+rows              the shared per-query row-walk primitives: Mosaic lowers
+                  no data-dependent vector gather, so the store kernels
+                  load whole 128-lane rows at a data-dependent row index
+                  and reduce each compare to a scalar
 splitorder_probe  two-level split-ordered hash probe (recursive-split
                   bucket directory + sorted-segment search)
 
@@ -37,7 +41,9 @@ The store kernels (skiplist_search, hash_probe) are never called directly
 by backends: `repro.store.exec` dispatches between them and their jnp
 references by execution mode (jnp | interpret | pallas), with bit-identical
 results guaranteed by tests/test_exec_modes.py. All kernels validate in
-interpret mode on CPU (tests/test_kernels.py); compiled mode targets TPU.
+interpret mode on CPU (tests/test_kernels.py); the store kernels compile
+for a described v5e in tests/test_chip_compile.py and match jnp on the
+chip in chip_smoke.py.
 
 Add a kernel ONLY for a hot spot the paper itself optimizes; keep the
 ref/ops/kernel split so the oracle and the layout adapter stay testable

@@ -7,15 +7,16 @@ the searchsorted-left position as ONE vector compare + sum-reduction, so
 the descent is `ceil(log_128(..))+1` full-tile steps instead of
 `num_levels+1` fan-out-4 steps (e.g. C=8192: 2 blocked vs 12 level-major).
 
-TPU mapping:
+TPU mapping (the per-query row walk of `kernels.rows`):
   * block-major layout (`core.layout.bskiplist_layout`): index levels are a
-    [L, W] rectangle, terminal a flat [NB*128] plane — whole-array
-    BlockSpecs keep both VMEM-resident (W <= C/128 u32 cells per row, tiny
-    next to the terminal planes the level-major kernel already holds).
-  * queries tile [T] per grid step; keys travel as (hi, lo) u32 pairs with
-    the shared `key_lt` compare (searchsorted-left needs strict <).
-  * each step is a dynamic gather of one 128-wide node row (same mosaic
-    dynamic_gather as the 4-wide child probe, just full-tile).
+    [L, W] rectangle, terminal a flat [NB*128] plane — handed over as
+    [L, W/128, 128] and [NB, 128] rows, so a fat node IS one 128-lane row;
+    whole-array BlockSpecs keep both VMEM-resident.
+  * queries tile [T] per grid step in SMEM; keys travel as (hi, lo) u32
+    pairs with the shared `key_lt` compare (searchsorted-left needs
+    strict <).
+  * each step loads one node row at a data-dependent row index and counts
+    its entries below the query (one vector compare + one reduction).
 """
 from __future__ import annotations
 
@@ -26,79 +27,78 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.layout import BSKIP_BLOCK, key_lt as _lt
+from repro.kernels import rows as R
+from repro.kernels.skiplist_search.kernel import term_match
 
 
-def block_walk(qh, ql, blk_hi, blk_lo, term_hi, term_lo, term_mark, *,
-               levels: int, block: int = BSKIP_BLOCK):
-    """The in-kernel block-major descent body: exactly `levels` + 1
-    whole-block compares (index rows top-down, then the terminal block).
-    Shared with the fused tier kernels (`kernels/tier_find`,
-    `kernels/tier_apply`), so the blocked warm-tier walk has exactly one
-    implementation. Returns (found bool[T], term idx i32[T])."""
-    t = qh.shape[0]
-    B = block
-    W = blk_hi.shape[1]
-    nb = term_hi.shape[0] // B
 
-    i = jnp.zeros((t,), jnp.int32)              # root: node 0 of row L-1
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (t, B), 1)
+def block_walk(qh, ql, bh_ref, bl_ref, th_ref, tl_ref, tm_ref, *,
+               levels: int):
+    """The in-kernel block-major descent body for ONE query: exactly
+    `levels` + 1 whole-node compares (index rows top-down, then the
+    terminal block). Shared with the fused tier kernels
+    (`kernels/tier_find`, `kernels/tier_apply`), so the blocked warm-tier
+    walk has exactly one implementation. bh/bl are [L, W/128, 128] node
+    rows, th/tl/tm the [NB, 128] terminal blocks. Returns (found bool,
+    term idx i32)."""
+    B = R.LANES
+    nodes = bh_ref.shape[1]
+    nb = th_ref.shape[0]
+    i = jnp.int32(0)                            # root: node 0 of row L-1
     for r in range(levels - 1, -1, -1):
-        base = jnp.clip(i, 0, W // B - 1) * B
-        idx = base[:, None] + lanes
-        eh = jnp.take(blk_hi[r], idx.reshape(-1), axis=0).reshape(t, B)
-        el = jnp.take(blk_lo[r], idx.reshape(-1), axis=0).reshape(t, B)
-        lt = _lt(eh, el, qh[:, None], ql[:, None])
-        sel = jnp.sum(lt, axis=1, dtype=jnp.int32)  # searchsorted-left
-        i = base + sel                               # child node / block id
+        node = jnp.clip(i, 0, nodes - 1)
+        sel = R.count(_lt(bh_ref[r, pl.ds(node, 1), :],
+                          bl_ref[r, pl.ds(node, 1), :], qh, ql))
+        i = node * B + sel                      # child node / block id
     blk = jnp.clip(i, 0, nb - 1)
-    idx = blk[:, None] * B + lanes
-    eh = jnp.take(term_hi, idx.reshape(-1), axis=0).reshape(t, B)
-    el = jnp.take(term_lo, idx.reshape(-1), axis=0).reshape(t, B)
-    lt = _lt(eh, el, qh[:, None], ql[:, None])
-    sel = jnp.sum(lt, axis=1, dtype=jnp.int32)
-    i = jnp.clip(blk * B + sel, 0, term_hi.shape[0] - 1)
-    fh = jnp.take(term_hi, i, axis=0)
-    fl = jnp.take(term_lo, i, axis=0)
-    fm = jnp.take(term_mark, i, axis=0)
-    return (fh == qh) & (fl == ql) & (fm == 0), i
+    sel = R.count(_lt(th_ref[pl.ds(blk, 1), :], tl_ref[pl.ds(blk, 1), :], qh,
+                      ql))
+    i = jnp.clip(blk * B + sel, 0, nb * B - 1)
+    return term_match(qh, ql, i, th_ref, tl_ref, tm_ref), i
+
+
+def block_rows(blk_hi, blk_lo, term_hi, term_lo, term_mark,
+               block: int = BSKIP_BLOCK):
+    """Block-major layout planes -> the row forms `block_walk` reads. A
+    node must be one 128-lane row."""
+    if block != R.LANES:
+        raise ValueError(f"block_walk needs {R.LANES}-key nodes, got "
+                         f"block={block}")
+    L, W = blk_hi.shape
+    return (blk_hi.reshape(L, W // block, block),
+            blk_lo.reshape(L, W // block, block),
+            term_hi.reshape(-1, block), term_lo.reshape(-1, block),
+            term_mark.astype(jnp.int32).reshape(-1, block))
 
 
 def _bw_kernel(qh_ref, ql_ref, bh_ref, bl_ref, th_ref, tl_ref, tm_ref,
-               found_ref, idx_ref, *, levels: int, block: int):
-    found, i = block_walk(qh_ref[...], ql_ref[...], bh_ref[...], bl_ref[...],
-                          th_ref[...], tl_ref[...], tm_ref[...],
-                          levels=levels, block=block)
-    found_ref[...] = found.astype(jnp.int8)
-    idx_ref[...] = i
+               found_ref, idx_ref, *, levels: int):
+    def one(q):
+        found, i = block_walk(qh_ref[q], ql_ref[q], bh_ref, bl_ref, th_ref,
+                              tl_ref, tm_ref, levels=levels)
+        found_ref[q] = found.astype(jnp.int32)
+        idx_ref[q] = i
+    R.for_each_query(qh_ref.shape[0], one)
 
 
 def bskiplist_walk_tiles(q_hi, q_lo, blk_hi, blk_lo, term_hi, term_lo,
-                         term_mark, *, block: int = BSKIP_BLOCK,
-                         tile: int = 256, interpret: bool = True):
-    """q_*: [T]; blk_*: [L, W]; term_*: [NB*B]. Returns (found i8[T],
-    idx i32[T])."""
+                         term_mark, *, block: int = BSKIP_BLOCK, tile: int,
+                         interpret: bool = True):
+    """q_*: [T] (T a multiple of `tile`); blk_*: [L, W]; term_*: [NB*B].
+    Returns (found i32[T], idx i32[T])."""
     t = q_hi.shape[0]
     L = blk_hi.shape[0]
     if t == 0:   # empty batch: same contract as the jnp reference
-        return (jnp.zeros((0,), jnp.int8), jnp.zeros((0,), jnp.int32))
-    tile = min(tile, t)
+        return (jnp.zeros((0,), jnp.int32), jnp.zeros((0,), jnp.int32))
     assert t % tile == 0
-    grid = (t // tile,)
-    whole = lambda a: pl.BlockSpec(a.shape, lambda g: (0,) * a.ndim)
-
-    kernel = functools.partial(_bw_kernel, levels=L, block=block)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile,), lambda g: (g,)),
-            pl.BlockSpec((tile,), lambda g: (g,)),
-            whole(blk_hi), whole(blk_lo),
-            whole(term_hi), whole(term_lo), whole(term_mark),
-        ],
-        out_specs=[pl.BlockSpec((tile,), lambda g: (g,)),
-                   pl.BlockSpec((tile,), lambda g: (g,))],
-        out_shape=[jax.ShapeDtypeStruct((t,), jnp.int8),
-                   jax.ShapeDtypeStruct((t,), jnp.int32)],
+    planes = block_rows(blk_hi, blk_lo, term_hi, term_lo, term_mark, block)
+    qspec = R.smem_tile(tile)
+    kernel = functools.partial(_bw_kernel, levels=L)
+    return R.pallas_call(
+        kernel, q_hi, q_lo, *planes,
+        grid=(t // tile,),
+        in_specs=[qspec, qspec] + [R.vmem_whole(a) for a in planes],
+        out_specs=[qspec, qspec],
+        out_shape=[jax.ShapeDtypeStruct((t,), jnp.int32)] * 2,
         interpret=interpret,
-    )(q_hi, q_lo, blk_hi, blk_lo, term_hi, term_lo, term_mark)
+    )

@@ -17,17 +17,18 @@ import jax.numpy as jnp
 from repro.core.bits import KEY_INF
 from repro.core.layout import BSKIP_BLOCK, bskiplist_layout, split_u64
 from repro.kernels.bskiplist_walk.kernel import bskiplist_walk_tiles
+from repro.kernels.rows import query_tiles
 
 
-def bskiplist_find(s, queries, *, block: int = BSKIP_BLOCK, tile: int = 256,
-                   interpret: bool = True):
+def bskiplist_find(s, queries, *, block: int = BSKIP_BLOCK,
+                   tile: int | None = None, interpret: bool = True):
     """Batched Find on a DetSkiplist via the blocked Pallas kernel — same
     contract as core.det_skiplist.find_batch: (found bool[T], vals u64[T],
     idx int32[T]). Not jitted: callable from inside jitted/shard_mapped
     store steps."""
     t = queries.shape[0]
-    pad = (-t) % tile
-    qp = jnp.pad(queries, (0, pad), constant_values=KEY_INF)
+    tp, tile = query_tiles(t, tile)
+    qp = jnp.pad(queries, (0, tp - t), constant_values=KEY_INF)
     qh, ql = split_u64(qp)
     lay = bskiplist_layout(s, block)
     # named scope: visible as obs.kernel.bskiplist_walk in jax.profiler
@@ -46,7 +47,7 @@ def bskiplist_find(s, queries, *, block: int = BSKIP_BLOCK, tile: int = 256,
 
 @partial(jax.jit, static_argnames=("block", "tile", "interpret"))
 def bskiplist_search(s, queries, *, block: int = BSKIP_BLOCK,
-                     tile: int = 256, interpret: bool = True):
+                     tile: int | None = None, interpret: bool = True):
     """Jitted standalone form of `bskiplist_find`."""
     return bskiplist_find(s, queries, block=block, tile=tile,
                           interpret=interpret)

@@ -15,9 +15,11 @@ import jax.numpy as jnp
 from repro.core.bits import EMPTY
 from repro.core.layout import bucket_layout, hash_slot, split_u64
 from repro.kernels.hash_probe.kernel import hash_probe_tiles
+from repro.kernels.rows import query_tiles
 
 
-def fixed_hash_find_cols(h, keys, *, tile: int = 256, interpret: bool = True):
+def fixed_hash_find_cols(h, keys, *, tile: int | None = None,
+                         interpret: bool = True):
     """Batched probe of a FixedHash via the Pallas kernel — same contract as
     core.hashtable.fixed_find_cols: (found bool[K], vals u64[K], col i32[K]).
     The kernel already emits the hit column (argmax over the bucket row, the
@@ -25,8 +27,8 @@ def fixed_hash_find_cols(h, keys, *, tile: int = 256, interpret: bool = True):
     stack's eviction-policy metadata refresh costs nothing. Not jitted:
     callable from inside jitted/shard_mapped store steps."""
     t = keys.shape[0]
-    pad = (-t) % tile
-    kp = jnp.pad(keys, (0, pad), constant_values=EMPTY)
+    tp, tile = query_tiles(t, tile)
+    kp = jnp.pad(keys, (0, tp - t), constant_values=EMPTY)
     slots = hash_slot(kp, h.num_slots)
     qh, ql = split_u64(kp)
     lay = bucket_layout(h.keys)
@@ -41,13 +43,14 @@ def fixed_hash_find_cols(h, keys, *, tile: int = 256, interpret: bool = True):
     return found, vals, col
 
 
-def fixed_hash_find(h, keys, *, tile: int = 256, interpret: bool = True):
+def fixed_hash_find(h, keys, *, tile: int | None = None,
+                    interpret: bool = True):
     """(found, vals) form of `fixed_hash_find_cols` — the contract of
     core.hashtable.fixed_find."""
     return fixed_hash_find_cols(h, keys, tile=tile, interpret=interpret)[:2]
 
 
 @partial(jax.jit, static_argnames=("tile", "interpret"))
-def hash_probe(h, keys, *, tile: int = 256, interpret: bool = True):
+def hash_probe(h, keys, *, tile: int | None = None, interpret: bool = True):
     """Jitted standalone form of `fixed_hash_find`."""
     return fixed_hash_find(h, keys, tile=tile, interpret=interpret)

@@ -2,17 +2,19 @@
 
 Pop-min on the deterministic skiplist is rank selection over the live
 terminal prefix: the j-th pop lane of a plan extracts the j-th smallest
-live key. The kernel computes the live-prefix cumsum (the SAME
-live = unmarked & non-padding formula as `core.det_skiplist.range_query`),
-rank-selects each lane's key with a first-true argmax (the Mosaic-safe
-spelling of searchsorted-left over a monotone prefix), then feeds the
-selected keys through the shared `skiplist_search.level_walk` descent so
-the key -> terminal-index mapping has exactly one implementation across
-FIND and POP.
+live key. The live-prefix count (the SAME live = unmarked & non-padding
+formula as `core.det_skiplist.range_query`) is a cumsum, which Mosaic does
+not lower, so it is computed on the host side of the call and handed to
+the kernel as one more plane. Per lane the kernel binary-searches that
+monotone prefix for the first position reaching the rank (searchsorted-
+left), then feeds the selected key through the shared
+`skiplist_search.level_walk` descent so the key -> terminal-index mapping
+has exactly one implementation across FIND and POP.
 
 Same layout contract as `kernels/skiplist_search`: level-major index stack
-([L, C1] u32 x3) + flat terminal planes ([C] u32 hi/lo + i8 marks), all
-VMEM-resident via whole-array BlockSpecs; ranks tile [T] per grid step.
+([L, C1] u32 x3) + flat terminal planes ([C] u32 hi/lo + marks), all
+VMEM-resident as `kernels.rows` row forms; ranks tile [T] per grid step in
+SMEM.
 """
 from __future__ import annotations
 
@@ -20,72 +22,82 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+import numpy as np
 
-from repro.kernels.skiplist_search.kernel import level_walk
+from repro.kernels import rows as R
+from repro.kernels.skiplist_search.kernel import level_rows, level_walk
 
-# plain int (not a jnp scalar): pallas kernels cannot capture traced
-# constants, and the weakly-typed literal folds into the comparisons
-_INF32 = 0xFFFFFFFF
+# numpy scalar, not a jnp array: pallas_call rejects closure-captured
+# jax-array constants, while numpy scalars inline as literals at trace time
+_INF32 = np.uint32(0xFFFFFFFF)
 
 
-def rank_select(ranks, mask, term_hi, term_lo, term_mark):
-    """The in-kernel rank-select body: live-prefix cumsum + first-true
-    argmax. Returns (found bool[T], key_hi u32[T], key_lo u32[T]) — lanes
-    whose rank exceeds the live population come back found=False with
-    KEY_INF keys, so the downstream level walk cannot match them against a
-    live entry."""
-    t = ranks.shape[0]
-    live = (term_mark == 0) & ~((term_hi == _INF32) & (term_lo == _INF32))
-    prefix = jnp.cumsum(live.astype(jnp.int32))            # [C] inclusive
-    total = prefix[-1]
-    want = ranks.astype(jnp.int32) + 1
+def rank_select(rank, mask, pre_ref, th_ref, tl_ref, *, cap: int):
+    """The in-kernel rank-select body for ONE lane: the first terminal
+    position whose live-prefix count reaches rank + 1. Returns (found bool,
+    key_hi, key_lo) — the key as [1, 1] u32 vectors; a lane whose rank
+    exceeds the live population comes back found=False with KEY_INF, so
+    the downstream level walk cannot match it against a live entry."""
+    want = rank + 1
+    total = R.read_i32(pre_ref, cap - 1)
     found = (mask != 0) & (want >= 1) & (want <= total)
-    # first index with prefix >= want (== searchsorted-left on a monotone
-    # prefix); no true -> 0, which `found` already excludes
-    hit = prefix[None, :] >= want[:, None]                  # [T, C]
-    i = jnp.argmax(hit, axis=1).astype(jnp.int32)
-    kh = jnp.where(found, jnp.take(term_hi, i, axis=0), _INF32)
-    kl = jnp.where(found, jnp.take(term_lo, i, axis=0), _INF32)
+
+    def step(c):
+        lo, hi = c
+        mid = (lo + hi) // 2
+        reached = R.read_i32(pre_ref, mid) >= want
+        return jnp.where(reached, lo, mid + 1), jnp.where(reached, mid, hi)
+
+    lo, _ = jax.lax.while_loop(lambda c: c[0] < c[1], step,
+                               (jnp.int32(0), jnp.int32(cap)))
+    i = jnp.minimum(lo, cap - 1)
+    kh = jnp.where(found, R.read_u32(th_ref, i), _INF32)
+    kl = jnp.where(found, R.read_u32(tl_ref, i), _INF32)
     return found, kh, kl
 
 
-def _pq_kernel(rk_ref, mk_ref, lh_ref, ll_ref, lc_ref, th_ref, tl_ref,
-               tm_ref, found_ref, idx_ref, *, levels: int, fanout: int):
-    th, tl, tm = th_ref[...], tl_ref[...], tm_ref[...]
-    sel, kh, kl = rank_select(rk_ref[...], mk_ref[...], th, tl, tm)
-    walked, i = level_walk(kh, kl, lh_ref[...], ll_ref[...], lc_ref[...],
-                           th, tl, tm, levels=levels, fanout=fanout)
-    found_ref[...] = (sel & walked).astype(jnp.int8)
-    idx_ref[...] = jnp.where(sel & walked, i, 0)
+def live_prefix(term_hi, term_lo, term_mark):
+    """Inclusive count of live (unmarked, non-padding) terminal entries."""
+    live = (term_mark == 0) & ~((term_hi == _INF32) & (term_lo == _INF32))
+    return jnp.cumsum(live.astype(jnp.int32)).astype(jnp.int32)
+
+
+def _pq_kernel(rk_ref, mk_ref, pre_ref, lh_ref, ll_ref, lc_ref, th_ref,
+               tl_ref, tm_ref, found_ref, idx_ref, *, levels: int,
+               fanout: int, c1: int, cap: int):
+    def one(q):
+        sel, kh, kl = rank_select(rk_ref[q], mk_ref[q], pre_ref, th_ref,
+                                  tl_ref, cap=cap)
+        walked, i = level_walk(kh, kl, lh_ref, ll_ref, lc_ref, th_ref,
+                               tl_ref, tm_ref, levels=levels, fanout=fanout,
+                               c1=c1, cap=cap)
+        hit = sel & walked
+        found_ref[q] = hit.astype(jnp.int32)
+        idx_ref[q] = jnp.where(hit, i, 0)
+    R.for_each_query(rk_ref.shape[0], one)
 
 
 def pq_pop_tiles(ranks, mask, lvl_hi, lvl_lo, lvl_child, term_hi, term_lo,
-                 term_mark, *, tile: int = 256, interpret: bool = True):
-    """ranks i32[T], mask i8[T]; lvl_*: [L, C1]; term_*: [C]. Returns
-    (found i8[T], term idx i32[T])."""
+                 term_mark, *, tile: int, interpret: bool = True):
+    """ranks i32[T], mask i32[T] (T a multiple of `tile`); lvl_*: [L, C1];
+    term_*: [C]. Returns (found i32[T], term idx i32[T])."""
     t = ranks.shape[0]
-    L, _ = lvl_hi.shape
+    L, c1 = lvl_hi.shape
+    cap = term_hi.shape[0]
     if t == 0:   # empty batch: same contract as the jnp reference
-        return (jnp.zeros((0,), jnp.int8), jnp.zeros((0,), jnp.int32))
-    tile = min(tile, t)
+        return (jnp.zeros((0,), jnp.int32), jnp.zeros((0,), jnp.int32))
     assert t % tile == 0
-    grid = (t // tile,)
-    whole = lambda a: pl.BlockSpec(a.shape, lambda g: (0,) * a.ndim)
-
-    kernel = functools.partial(_pq_kernel, levels=L, fanout=4)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile,), lambda g: (g,)),
-            pl.BlockSpec((tile,), lambda g: (g,)),
-            whole(lvl_hi), whole(lvl_lo), whole(lvl_child),
-            whole(term_hi), whole(term_lo), whole(term_mark),
-        ],
-        out_specs=[pl.BlockSpec((tile,), lambda g: (g,)),
-                   pl.BlockSpec((tile,), lambda g: (g,))],
-        out_shape=[jax.ShapeDtypeStruct((t,), jnp.int8),
-                   jax.ShapeDtypeStruct((t,), jnp.int32)],
+    planes = (R.as_rows(live_prefix(term_hi, term_lo, term_mark), 0),
+              *level_rows(lvl_hi, lvl_lo, lvl_child, term_hi, term_lo,
+                          term_mark))
+    qspec = R.smem_tile(tile)
+    kernel = functools.partial(_pq_kernel, levels=L, fanout=4, c1=c1,
+                               cap=cap)
+    return R.pallas_call(
+        kernel, ranks, mask, *planes,
+        grid=(t // tile,),
+        in_specs=[qspec, qspec] + [R.vmem_whole(a) for a in planes],
+        out_specs=[qspec, qspec],
+        out_shape=[jax.ShapeDtypeStruct((t,), jnp.int32)] * 2,
         interpret=interpret,
-    )(ranks, mask, lvl_hi, lvl_lo, lvl_child, term_hi, term_lo, term_mark)
+    )

@@ -14,17 +14,20 @@ import jax.numpy as jnp
 from repro.core.bits import KEY_INF
 from repro.core.layout import skiplist_layout
 from repro.kernels.pq_pop.kernel import pq_pop_tiles
+from repro.kernels.rows import query_tiles
 
 
-def pq_pop_ranks(s, ranks, mask, *, tile: int = 256, interpret: bool = True):
+def pq_pop_ranks(s, ranks, mask, *, tile: int | None = None,
+                 interpret: bool = True):
     """Rank-select the rank-th smallest live key per lane on a DetSkiplist
     via the Pallas kernel — same contract as det_skiplist.pop_rank_select:
     (found bool[K], keys u64[K], idx int32[K]). Not jitted: callable from
     inside jitted/shard_mapped store steps."""
     t = ranks.shape[0]
-    pad = (-t) % tile
-    rp = jnp.pad(jnp.asarray(ranks, jnp.int32), (0, pad), constant_values=-1)
-    mp = jnp.pad(jnp.asarray(mask, bool), (0, pad)).astype(jnp.int8)
+    tp, tile = query_tiles(t, tile)
+    rp = jnp.pad(jnp.asarray(ranks, jnp.int32), (0, tp - t),
+                 constant_values=-1)
+    mp = jnp.pad(jnp.asarray(mask, bool), (0, tp - t)).astype(jnp.int32)
     lay = skiplist_layout(s)
     # named scope: visible as obs.kernel.pq_pop in jax.profiler timelines /
     # lowered HLO (span taxonomy in store/obs.py)

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.core.bits import KEY_INF
 from repro.core.layout import skiplist_layout, split_u64
+from repro.kernels.rows import query_tiles
 from repro.kernels.skiplist_search.kernel import skiplist_search_tiles
 
 
@@ -24,13 +25,14 @@ def stack_levels(s):
     return lay.lvl_hi, lay.lvl_lo, lay.lvl_child
 
 
-def skiplist_find(s, queries, *, tile: int = 256, interpret: bool = True):
+def skiplist_find(s, queries, *, tile: int | None = None,
+                  interpret: bool = True):
     """Batched Find on a DetSkiplist via the Pallas kernel — same contract as
     core.det_skiplist.find_batch: (found bool[T], vals u64[T], idx int32[T]).
     Not jitted: callable from inside jitted/shard_mapped store steps."""
     t = queries.shape[0]
-    pad = (-t) % tile
-    qp = jnp.pad(queries, (0, pad), constant_values=KEY_INF)
+    tp, tile = query_tiles(t, tile)
+    qp = jnp.pad(queries, (0, tp - t), constant_values=KEY_INF)
     qh, ql = split_u64(qp)
     lay = skiplist_layout(s)
     # named scope: visible as obs.kernel.skiplist_search in jax.profiler
@@ -48,6 +50,7 @@ def skiplist_find(s, queries, *, tile: int = 256, interpret: bool = True):
 
 
 @partial(jax.jit, static_argnames=("tile", "interpret"))
-def skiplist_search(s, queries, *, tile: int = 256, interpret: bool = True):
+def skiplist_search(s, queries, *, tile: int | None = None,
+                    interpret: bool = True):
     """Jitted standalone form of `skiplist_find`."""
     return skiplist_find(s, queries, tile=tile, interpret=interpret)

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.core.bits import KEY_INF, bitrev64, hash64
 from repro.core.layout import split_u64
+from repro.kernels.rows import query_tiles
 from repro.kernels.splitorder_probe.kernel import splitorder_probe_tiles
 
 
@@ -26,14 +27,14 @@ def _table_of(h, keys):
     return (hash64(keys) >> jnp.uint64(64 - t_bits)).astype(jnp.int32)
 
 
-def twolevel_splitorder_probe(h, keys, *, tile: int = 256,
+def twolevel_splitorder_probe(h, keys, *, tile: int | None = None,
                               interpret: bool = True):
     """Batched probe of a TwoLevelSplitOrder via the Pallas kernel — same
     contract as core.splitorder.twolevel_splitorder_find. Not jitted:
     callable from inside jitted/shard_mapped store steps."""
     t = keys.shape[0]
-    pad = (-t) % tile
-    kp = jnp.pad(keys, (0, pad), constant_values=KEY_INF)
+    tp, tile = query_tiles(t, tile)
+    kp = jnp.pad(keys, (0, tp - t), constant_values=KEY_INF)
     rkq = bitrev64(hash64(kp))
     tbl = _table_of(h, kp)
     qrh, qrl = split_u64(rkq)

@@ -17,31 +17,31 @@ Shared bodies, not copies: the hot probe is
 `kernels.hash_probe.kernel.bucket_probe`, the warm walk is
 `kernels.skiplist_search.kernel.level_walk` (or
 `kernels.bskiplist_walk.kernel.block_walk` when the stack selected the
-block-major warm layout — no child plane in that case) — the same
-functions the fused find uses. The lane math mirrors `core.hashtable.bucket_insert_plan` /
-`kernels.tier_apply.ref.hot_insert_evict` term by term over (hi, lo) u32
-planes, so fused/unfused bit-identity is by construction.
+block-major warm layout — no child plane in that case) and the spill probe
+is `kernels.tier_find.kernel.spill_run_probe` — the same functions the
+fused find uses. The lane math mirrors
+`core.hashtable.bucket_insert_plan` / `kernels.tier_apply.ref.
+hot_insert_evict` term by term over (hi, lo) u32 planes, so fused/unfused
+bit-identity is by construction.
 
-Scalar-prefetched spill probes (`pltpu.PrefetchScalarGridSpec`): the
-`run_offsets` boundary plane and the eviction cap arrive as SMEM scalars
-BEFORE the grid runs, and the grid iterates over fixed-size CHUNKS of the
-spill key/tombstone planes — each step binary-searches every run's
-intersection with its chunk and accumulates hits in VMEM scratch (the
-sequential TPU grid keeps scratch live across steps). The spill tier
-therefore never needs to be VMEM-resident as a whole: chunks stream
-through, which is the unlock for HBM/host-resident spill tiers of millions
-of keys. All query-plane work (membership compose + insert prologue) is
-predicated onto the LAST grid step.
+Lanes run in sorted (slot, key) order, one at a time (the per-lane row walk
+of `kernels.rows`): every cumsum of the reference is a running count over
+that order, carried across lanes — and across grid steps — in three SMEM
+counters (lanes of the current key run that try the hot tier, hot-insert
+candidates of the current slot run, evicting lanes so far). The run-start
+planes say where a run begins, which resets its counter.
 
-Victim selection without an in-kernel argsort: the reference takes entry
+Victim selection without an argsort: the reference takes entry
 `clip(ev_rank, 0, B-1)` of a stable argsort over the policy score row.
 Stable-sort position of column j is `#{k: (score_k, k) <lex (score_j, j)}`
-— a counting rank, computed here with a static loop over the B bucket
-columns (B is a small static width; positions are distinct, so exactly one
-column matches each target rank). Provably equal to the argsort take.
+— a counting rank, computed for all B columns at once on a [B, B] tile
+(positions are distinct, so exactly one column matches each target rank).
+Provably equal to the argsort take. The nth-empty placement column is the
+same [B, B] count over the empty mask.
 
-Outputs (all [K], sorted (slot, key) lane order; i8 flags / i32 columns):
-in_warm, in_spill, placed, exists, dup, need_ev, col, vcol, ecol.
+Outputs (all [K], sorted (slot, key) lane order, i32): a flag word (bit 0
+in_warm, 1 in_spill, 2 placed, 3 exists, 4 dup, 5 need_ev), col, vcol,
+ecol.
 """
 from __future__ import annotations
 
@@ -53,267 +53,188 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.layout import BSKIP_BLOCK, key_lt as _lt
-from repro.kernels.bskiplist_walk.kernel import block_walk
+from repro.core.layout import BSKIP_BLOCK
+from repro.kernels import rows as R
 from repro.kernels.hash_probe.kernel import bucket_probe
-from repro.kernels.skiplist_search.kernel import level_walk
+from repro.kernels.tier_find.kernel import (spill_rows, spill_run_probe,
+                                            warm_rows, warm_walk)
 
 # numpy scalar, not a jnp array: pallas_call rejects closure-captured
 # jax-array constants, while numpy scalars inline as literals at trace time
 _U32MAX = np.uint32(0xFFFFFFFF)
+_I32MAX = 2 ** 31 - 1
+_I32MIN = -(2 ** 31)
+FLAGS = ("in_warm", "in_spill", "placed", "exists", "dup", "need_ev")
 
 
-def spill_chunk_probe(qh, ql, sp_hi, sp_lo, sp_dead, off, cbase, *,
-                      max_runs: int, chunk: int):
-    """One chunk's contribution to the cold-tier membership probe: binary
-    search each run's intersection with the chunk window
-    [cbase, cbase + chunk) — `sp_*` are the CHUNK blocks, indexed locally.
-    Run keys strictly increase, so a query's match position lies in exactly
-    one chunk: windows that don't contain it converge to a boundary or a
-    different key and stay dead. ORing the per-chunk results over the grid
-    reproduces `kernels.tier_find.kernel.spill_run_probe`'s found bit
-    exactly. Returns found bool[T] for this chunk."""
-    t = qh.shape[0]
-    r = max_runs
-    cend = cbase + chunk
-    lo = jnp.broadcast_to(jnp.clip(off[:r], cbase, cend)[None, :],
-                          (t, r)).astype(jnp.int32)
-    end = jnp.broadcast_to(jnp.clip(off[1:r + 1], cbase, cend)[None, :],
-                           (t, r)).astype(jnp.int32)
-    hi = end
-    qh2, ql2 = qh[:, None], ql[:, None]
-    for _ in range(max(chunk.bit_length(), 1)):
-        cont = lo < hi
-        mid = (lo + hi) // 2
-        lmid = jnp.clip(mid - cbase, 0, chunk - 1)
-        mh = jnp.take(sp_hi, lmid.reshape(-1), axis=0).reshape(t, r)
-        ml = jnp.take(sp_lo, lmid.reshape(-1), axis=0).reshape(t, r)
-        less = _lt(mh, ml, qh2, ql2)            # sp[mid] < q
-        lo = jnp.where(cont & less, mid + 1, lo)
-        hi = jnp.where(cont & ~less, mid, hi)
-    lpos = jnp.clip(lo - cbase, 0, chunk - 1)
-    p_hi = jnp.take(sp_hi, lpos.reshape(-1), axis=0).reshape(t, r)
-    p_lo = jnp.take(sp_lo, lpos.reshape(-1), axis=0).reshape(t, r)
-    p_dead = jnp.take(sp_dead, lpos.reshape(-1), axis=0).reshape(t, r)
-    live = (lo < end) & (p_hi == qh2) & (p_lo == ql2) & (p_dead == 0)
-    return jnp.any(live, axis=1)
+def nth_true_col(mask, n):
+    """Column of the n-th (1-based) true entry of a [1, B] row, B if the
+    row has fewer: `core.hashtable._nth_empty` on one row."""
+    b = mask.shape[1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (b, b), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (b, b), 1)
+    m = jnp.broadcast_to(mask, (b, b))
+    upto = jnp.sum((m & (col <= row)).astype(jnp.int32), axis=1,
+                   keepdims=True)                     # trues in cols <= j
+    here = jnp.max((m & (col == row)).astype(jnp.int32), axis=1,
+                   keepdims=True)                     # mask[j]
+    return R.first_lane((here > 0) & (upto == n), row[:, :1], b)
 
 
-def _ta_kernel(*refs, levels: int, fanout: int, policy: str,
-               warm_blocked: bool, block: int, has_spill: bool,
-               max_runs: int, chunk: int, n_chunks: int):
+def stable_rank_col(score, target):
+    """The column whose stable-sort position in the [1, B] score row is
+    `target` (0 if none): the argsort take as a counting rank."""
+    b = score.shape[1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (b, b), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (b, b), 1)
+    sc = jnp.broadcast_to(score, (b, b))              # [j, k] = score[k]
+    sj = jnp.max(jnp.where(col == row, sc, _I32MIN), axis=1,
+                 keepdims=True)                       # score[j]
+    before = (sc < sj) | ((sc == sj) & (col < row))
+    pos = jnp.sum(before.astype(jnp.int32), axis=1, keepdims=True)
+    v = R.first_lane(pos == target, row[:, :1], b)
+    return jnp.where(v == b, 0, v)
+
+
+def _ta_kernel(*refs, n_warm: int, levels: int, c1: int, cap: int,
+               policy: str, has_spill: bool, max_runs: int, s: int,
+               tile: int):
+    (skh_ref, skl_ref, ss_ref, sm_ref, krs_ref, srs_ref, kh_ref, kl_ref,
+     meta_ref) = refs[:9]
+    warm = refs[9:9 + n_warm]
+    i = 9 + n_warm
     if has_spill:
-        off_ref, me_ref = refs[0], refs[1]
-        i = 2
-    else:
-        me_ref = refs[0]
-        i = 1
-    (skh_ref, skl_ref, ss_ref, sm_ref, krs_ref, srs_ref,
-     kh_ref, kl_ref, meta_ref, lh_ref, ll_ref) = refs[i:i + 11]
-    i += 11
-    if warm_blocked:    # block-major warm planes carry no child plane
-        lc_ref = None
-    else:
-        lc_ref = refs[i]
-        i += 1
-    th_ref, tl_ref, tm_ref = refs[i:i + 3]
-    i += 3
-    if has_spill:
-        sph_ref, spl_ref, spd_ref = refs[i:i + 3]
-        i += 3
-        outs = refs[i:i + 9]
-        acc_ref = refs[i + 9]
-    else:
-        outs = refs[i:i + 9]
-        acc_ref = None
+        spill = refs[i:i + 3]
+        off_ref = refs[i + 3]
+        i += 4
+    me_ref = refs[i]
+    flag_ref, col_ref, vcol_ref, ecol_ref = refs[i + 1:i + 5]
+    cnt = refs[i + 5]            # SMEM running counters, live across tiles
+    m_slots, b = kh_ref.shape
+    lane_b = jax.lax.broadcasted_iota(jnp.int32, (1, b), 1)
 
-    skh = skh_ref[...]
-    skl = skl_ref[...]
-    smb = sm_ref[...] != 0
-    k = skh.shape[0]
-    # membership queries: masked-off lanes probe with the KEY_INF sentinel,
-    # the dispatch layer's `where(mask, keys, KEY_INF)` in u32 planes
-    mqh = jnp.where(smb, skh, _U32MAX)
-    mql = jnp.where(smb, skl, _U32MAX)
+    g = pl.program_id(0)
 
-    if has_spill:
-        c = pl.program_id(0)
+    @pl.when(g == 0)
+    def _zero():
+        for j in range(3):
+            cnt[j] = 0
 
-        @pl.when(c == 0)
-        def _zero_acc():
-            acc_ref[...] = jnp.zeros((k,), jnp.int32)
-
-        off = jnp.stack([off_ref[i] for i in range(max_runs + 1)])
-        hit = spill_chunk_probe(mqh, mql, sph_ref[...], spl_ref[...],
-                                spd_ref[...], off, c * chunk,
-                                max_runs=max_runs, chunk=chunk)
-        acc_ref[...] = acc_ref[...] | hit.astype(jnp.int32)
-
-    @pl.when(pl.program_id(0) == n_chunks - 1)
-    def _apply_prologue():
-        ss = ss_ref[...]
-        b = kh_ref.shape[1]
-        m = kh_ref.shape[0]
-
-        # membership compose + fall-through (the exec.tier_find contract)
-        hot_any, _ = bucket_probe(mqh, mql, ss, kh_ref[...], kl_ref[...])
-        f_hot = hot_any & smb
-        if warm_blocked:
-            warm_found, _ = block_walk(mqh, mql, lh_ref[...], ll_ref[...],
-                                       th_ref[...], tl_ref[...],
-                                       tm_ref[...], levels=levels,
-                                       block=block)
-        else:
-            warm_found, _ = level_walk(mqh, mql, lh_ref[...], ll_ref[...],
-                                       lc_ref[...], th_ref[...],
-                                       tl_ref[...], tm_ref[...],
-                                       levels=levels, fanout=fanout)
-        f_warm = warm_found & smb
+    def one(q):
+        lane = g * tile + q
+        kh, kl, slot = skh_ref[q], skl_ref[q], ss_ref[q]
+        m = sm_ref[q] != 0
+        # membership: masked-off lanes probe with the KEY_INF sentinel, the
+        # dispatch layer's `where(mask, keys, KEY_INF)` in u32 planes
+        mqh = jnp.where(m, kh, _U32MAX)
+        mql = jnp.where(m, kl, _U32MAX)
+        f_hot = bucket_probe(mqh, mql, slot, kh_ref, kl_ref)[0] & m
+        f_warm = warm_walk(mqh, mql, warm, levels=levels, c1=c1,
+                           cap=cap)[0] & m
         if has_spill:
-            f_sp = (acc_ref[...] != 0) & smb
+            f_sp = spill_run_probe(mqh, mql, *spill, off_ref,
+                                   max_runs=max_runs, s=s)[0] & m
         else:
-            f_sp = jnp.zeros((k,), bool)
+            f_sp = jnp.bool_(False)
         in_warm = f_warm & ~f_hot
         in_spill = f_sp & ~f_hot & ~f_warm
+        ins = m & ~in_warm & ~in_spill          # lanes that try the hot tier
 
-        # insert mask after membership: lanes resident below never try hot
-        sm_ins = smb & ~in_warm & ~in_spill
-        smi = sm_ins.astype(jnp.int32)
+        # in-batch duplicate: an earlier lane of the same (slot, key) run
+        # also tries the hot tier
+        c_key = jnp.where(krs_ref[q] == lane, 0, cnt[0])
+        dup = ins & (c_key > 0)
+        cnt[0] = c_key + ins.astype(jnp.int32)
 
-        # in-batch duplicate: lane rank within its (slot, key) run — the
-        # `core.bits.dup_in_run` formula with host-precomputed run starts
-        krs = krs_ref[...]
-        c1 = jnp.cumsum(smi)
-        before_k = jnp.take(c1, krs, axis=0) - jnp.take(smi, krs, axis=0)
-        dup = sm_ins & ((c1 - smi - before_k) > 0)
-
-        # pre-batch bucket rows: one gather serves existence, the empty
-        # scan, and the victim metadata below
-        ssc = jnp.clip(ss, 0, m - 1)
-        rows_h = jnp.take(kh_ref[...], ssc, axis=0)
-        rows_l = jnp.take(kl_ref[...], ssc, axis=0)
-        hit_e = (rows_h == skh[:, None]) & (rows_l == skl[:, None])
-        ecol = jnp.argmax(hit_e, axis=1).astype(jnp.int32)
-        exists = sm_ins & jnp.any(hit_e, axis=1) & ~dup
-        cand = sm_ins & ~dup & ~exists
+        # pre-batch bucket row: existence, the empty scan, victim metadata
+        sc = jnp.clip(slot, 0, m_slots - 1)
+        rh = kh_ref[pl.ds(sc, 1), :]
+        rl = kl_ref[pl.ds(sc, 1), :]
+        hit_e = (rh == kh) & (rl == kl)
+        ecol = R.first_lane(hit_e, lane_b, b)
+        exists = ins & (ecol < b) & ~dup
+        cand = ins & ~dup & ~exists
 
         # within-slot candidate rank (`core.hashtable._seg_rank`)
-        srs = srs_ref[...]
-        ci = cand.astype(jnp.int32)
-        c2 = jnp.cumsum(ci)
-        before_s = jnp.where(
-            srs > 0, jnp.take(c2, jnp.maximum(srs - 1, 0), axis=0), 0)
-        rank = c2 - before_s - ci
+        rank = jnp.where(srs_ref[q] == lane, 0, cnt[1])
+        cnt[1] = rank + cand.astype(jnp.int32)
 
-        # nth-empty placement column (`core.hashtable._nth_empty`)
-        empty = (rows_h == _U32MAX) & (rows_l == _U32MAX)
-        cum_e = jnp.cumsum(empty.astype(jnp.int32), axis=1)
-        hit_n = empty & (cum_e == rank[:, None] + 1)
-        fit_e = jnp.any(hit_n, axis=1)
-        col_e = jnp.where(fit_e,
-                          jnp.argmax(hit_n, axis=1).astype(jnp.int32), b)
+        empty = (rh == _U32MAX) & (rl == _U32MAX)
+        col_e = nth_true_col(empty, rank + 1)
+        fit_e = col_e < b
 
         if policy != "none":
-            # victim selection: counting rank over the policy score row
-            # (see module docstring) — no in-kernel argsort needed
-            metar = jnp.take(meta_ref[...], ssc, axis=0)
-            n_empty = jnp.sum(empty.astype(jnp.int32), axis=1)
+            n_empty = R.count(empty)
             ev_rank = rank - n_empty
+            metar = meta_ref[pl.ds(sc, 1), :]
             score = metar if policy == "lru" else -metar
-            score = jnp.where(~empty, score, jnp.iinfo(jnp.int32).max)
-            tgt = jnp.clip(ev_rank, 0, b - 1)
-            iota_b = jax.lax.broadcasted_iota(jnp.int32, (k, b), 1)
-            vcol = jnp.zeros((k,), jnp.int32)
-            for j in range(b):
-                sj = score[:, j:j + 1]
-                less_j = (score < sj) | ((score == sj) & (iota_b < j))
-                pos_j = jnp.sum(less_j.astype(jnp.int32), axis=1)
-                vcol = jnp.where(pos_j == tgt, jnp.int32(j), vcol)
-            need_ev = cand & ~fit_e & (ev_rank < b - n_empty)
-            need_ev = need_ev & ((jnp.cumsum(need_ev.astype(jnp.int32)) - 1)
-                                 < me_ref[0])
+            score = jnp.where(empty, _I32MAX, score)
+            vcol = stable_rank_col(score, jnp.clip(ev_rank, 0, b - 1))
+            want_ev = cand & ~fit_e & (ev_rank < b - n_empty)
+            n_ev = cnt[2]
+            need_ev = want_ev & (n_ev < me_ref[0])
+            cnt[2] = n_ev + want_ev.astype(jnp.int32)
         else:
-            vcol = jnp.zeros((k,), jnp.int32)
-            need_ev = jnp.zeros((k,), bool)
+            vcol = jnp.int32(0)
+            need_ev = jnp.bool_(False)
 
         placed = (cand & fit_e) | need_ev
-        col = jnp.where(fit_e, col_e, vcol)
-
-        outs[0][...] = in_warm.astype(jnp.int8)
-        outs[1][...] = in_spill.astype(jnp.int8)
-        outs[2][...] = placed.astype(jnp.int8)
-        outs[3][...] = exists.astype(jnp.int8)
-        outs[4][...] = dup.astype(jnp.int8)
-        outs[5][...] = need_ev.astype(jnp.int8)
-        outs[6][...] = col
-        outs[7][...] = vcol
-        outs[8][...] = ecol
+        flags = (in_warm, in_spill, placed, exists, dup, need_ev)
+        word = jnp.int32(0)
+        for bit, f in enumerate(flags):
+            word = word | (f.astype(jnp.int32) << bit)
+        flag_ref[q] = word
+        col_ref[q] = jnp.where(fit_e, col_e, vcol)
+        vcol_ref[q] = vcol
+        ecol_ref[q] = jnp.where(ecol < b, ecol, 0)
+    R.for_each_query(skh_ref.shape[0], one)
 
 
 def tier_apply_tiles(sk_hi, sk_lo, slots, sm, krs, srs, key_hi, key_lo,
                      meta, lvl_hi, lvl_lo, lvl_child, term_hi, term_lo,
                      term_mark, max_evict, sp_hi=None, sp_lo=None,
                      sp_dead=None, run_off=None, *, policy: str,
-                     block: int = BSKIP_BLOCK, spill_chunk: int = 512,
+                     block: int = BSKIP_BLOCK, tile: int,
                      interpret: bool = True):
-    """sk_*: [K] u32 keys in sorted (slot, key) lane order; slots/krs/srs:
-    [K] i32 (slot per lane, key-run starts, slot-run starts); sm: [K] i8
-    insert mask; key_*/meta: [M, B]; lvl_*: [L, C1]; term_*: [C];
-    max_evict: [1] i32 (scalar-prefetched); sp_* [S] + run_off [R+1] i32
-    (scalar-prefetched) or None for a 2-tier stack. Returns the 9 outputs
-    listed in the module docstring. `lvl_child=None` selects the BLOCKED
-    warm walk: lvl_* then carry the `bskiplist_layout` [L, W] fat-node
-    rows and term_* its [NB*block] padded terminal planes."""
+    """sk_*: [K] u32 keys in sorted (slot, key) lane order (K a multiple of
+    `tile`); slots/krs/srs: [K] i32 (slot per lane, key-run starts,
+    slot-run starts); sm: [K] i32 insert mask; key_*/meta: [M, B];
+    lvl_*: [L, C1]; term_*: [C]; max_evict: [1] i32; sp_* [S] + run_off
+    [R+1] i32 or None for a 2-tier stack. Returns (flags, col, vcol, ecol)
+    as listed in the module docstring. `lvl_child=None` selects the
+    BLOCKED warm walk: lvl_* then carry the `bskiplist_layout` [L, W]
+    fat-node rows and term_* its [NB*block] padded terminal planes."""
     k = sk_hi.shape[0]
-    L = lvl_hi.shape[0]
-    warm_blocked = lvl_child is None
+    assert k % tile == 0
+    L, c1 = lvl_hi.shape
     has_spill = sp_hi is not None
-    tensors = [sk_hi, sk_lo, slots, sm, krs, srs, key_hi, key_lo, meta,
-               lvl_hi, lvl_lo]
-    if not warm_blocked:
-        tensors.append(lvl_child)
-    tensors += [term_hi, term_lo, term_mark]
-    whole = lambda a: pl.BlockSpec(a.shape, lambda g, *_: (0,) * a.ndim)
-    in_specs = [whole(a) for a in tensors]
-    scalars = [max_evict]
-    scratch = []
-    max_runs = 0
+    warm = warm_rows(lvl_hi, lvl_lo, lvl_child, term_hi, term_lo,
+                     term_mark, block)
+    qspec = R.smem_tile(tile)
+    ins = [sk_hi, sk_lo, slots, sm, krs, srs, key_hi, key_lo, meta, *warm]
+    in_specs = [qspec] * 6 + [R.vmem_whole(a) for a in ins[6:]]
+    max_runs = s = 0
     if has_spill:
-        s = sp_hi.shape[0]
-        chunk = min(spill_chunk, s)
-        # pad the spill planes to whole chunks; padded cells sit past every
-        # run boundary (off <= n <= S), so no window ever reaches them
-        pad = (-s) % chunk
-        if pad:
-            sp_hi = jnp.pad(sp_hi, (0, pad), constant_values=0xFFFFFFFF)
-            sp_lo = jnp.pad(sp_lo, (0, pad), constant_values=0xFFFFFFFF)
-            sp_dead = jnp.pad(sp_dead, (0, pad), constant_values=1)
-        n_chunks = (s + pad) // chunk
-        scalars = [run_off, max_evict]
-        tensors += [sp_hi, sp_lo, sp_dead]
-        cspec = pl.BlockSpec((chunk,), lambda g, *_: (g,))
-        in_specs += [cspec, cspec, cspec]
-        scratch = [pltpu.VMEM((k,), jnp.int32)]
+        spill = spill_rows(sp_hi, sp_lo, sp_dead)
+        ins += [*spill, run_off]
+        in_specs += [R.vmem_whole(a) for a in spill] + [R.smem_whole(run_off)]
         max_runs = run_off.shape[0] - 1
-    else:
-        chunk = 0
-        n_chunks = 1
-
-    out_dtypes = [jnp.int8] * 6 + [jnp.int32] * 3
-    kernel = functools.partial(_ta_kernel, levels=L, fanout=4,
-                               policy=policy, warm_blocked=warm_blocked,
-                               block=block, has_spill=has_spill,
-                               max_runs=max_runs, chunk=chunk,
-                               n_chunks=n_chunks)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(scalars),
-        grid=(n_chunks,),
+        s = sp_hi.shape[0]
+    ins.append(max_evict)
+    in_specs.append(R.smem_whole(max_evict))
+    kernel = functools.partial(_ta_kernel, n_warm=len(warm), levels=L, c1=c1,
+                               cap=term_hi.shape[0], policy=policy,
+                               has_spill=has_spill, max_runs=max_runs, s=s,
+                               tile=tile)
+    return R.pallas_call(
+        kernel, *ins,
+        grid=(k // tile,),
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((k,), lambda g, *_: (0,))] * 9,
-        scratch_shapes=scratch)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((k,), d) for d in out_dtypes],
+        out_specs=[qspec] * 4,
+        out_shape=[jax.ShapeDtypeStruct((k,), jnp.int32)] * 4,
+        scratch_shapes=[pltpu.SMEM((3,), jnp.int32)],
+        # the running counters make lane tiles a sequential scan
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(*scalars, *tensors)
+    )

@@ -21,12 +21,13 @@ from repro.core.bits import EMPTY
 from repro.core.layout import (bskiplist_layout, bucket_layout, hash_slot,
                                skiplist_layout, spill_layout, split_u64,
                                val_weight)
-from repro.kernels.tier_apply.kernel import tier_apply_tiles
+from repro.kernels.rows import query_tiles
+from repro.kernels.tier_apply.kernel import FLAGS, tier_apply_tiles
 
 
 def tier_apply_fused(hot, meta, clock, cold, spill, keys, vals, mask,
                      policy: str, max_evict, *, warm_layout: str = "level",
-                     spill_chunk: int = 512, interpret: bool = True):
+                     tile: int | None = None, interpret: bool = True):
     """One dispatch over the whole apply prologue. `hot` is a FixedHash
     (+ its [M, B] i32 `meta` plane and the batch `clock`), `cold` a
     DetSkiplist, `spill` a SpillTier or None. Returns the same 9-tuple as
@@ -53,7 +54,12 @@ def tier_apply_fused(hot, meta, clock, cold, spill, keys, vals, mask,
     krs = jax.lax.associative_scan(jnp.maximum, jnp.where(~same, idx, -1))
     srs = jnp.searchsorted(ss, ss, side="left").astype(jnp.int32)
 
-    skh, skl = split_u64(sk)
+    # pad the lane planes to whole tiles: padded lanes are masked off and
+    # each opens its own key and slot run, after every real lane
+    kp, tile = query_tiles(K, tile)
+    pad = lambda x, v: jnp.pad(x, (0, kp - K), constant_values=v)
+    tail = jnp.arange(K, kp, dtype=jnp.int32)
+    skh, skl = split_u64(pad(sk, EMPTY))
     blay = bucket_layout(hot.keys)
     if warm_layout == "block":
         wlay = bskiplist_layout(cold)
@@ -63,7 +69,9 @@ def tier_apply_fused(hot, meta, clock, cold, spill, keys, vals, mask,
         slay = skiplist_layout(cold)
         warm_planes = (slay.lvl_hi, slay.lvl_lo, slay.lvl_child,
                        slay.term_hi, slay.term_lo, slay.term_mark)
-    args = (skh, skl, ss, sm.astype(jnp.int8), krs.astype(jnp.int32), srs,
+    args = (skh, skl, pad(ss, 0), pad(sm.astype(jnp.int32), 0),
+            jnp.concatenate([krs.astype(jnp.int32), tail]),
+            jnp.concatenate([srs, tail]),
             blay.key_hi, blay.key_lo, meta) + warm_planes + (
             jnp.asarray(max_evict, jnp.int32).reshape(1),)
     kw = {}
@@ -75,16 +83,10 @@ def tier_apply_fused(hot, meta, clock, cold, spill, keys, vals, mask,
     # named scope: visible as obs.kernel.tier_apply in jax.profiler
     # timelines / lowered HLO (span taxonomy in store/obs.py)
     with jax.named_scope("obs.kernel.tier_apply"):
-        out = tier_apply_tiles(*args, **kw, policy=policy,
-                               spill_chunk=spill_chunk,
-                               interpret=interpret)
-    in_warm = out[0].astype(bool)
-    in_spill = out[1].astype(bool)
-    placed = out[2].astype(bool)
-    exists = out[3].astype(bool)
-    dup = out[4].astype(bool)
-    need_ev = out[5].astype(bool)
-    col, vcol, ecol = out[6], out[7], out[8]
+        word, col, vcol, ecol = (o[:K] for o in tier_apply_tiles(
+            *args, **kw, policy=policy, tile=tile, interpret=interpret))
+    (in_warm, in_spill, placed, exists, dup, need_ev) = (
+        ((word >> bit) & 1) == 1 for bit in range(len(FLAGS)))
 
     # u64 victim gathers from the PRE-batch rows (a key placed this batch
     # is never its own batch's victim)

@@ -16,11 +16,12 @@ import jax.numpy as jnp
 from repro.core.bits import KEY_INF
 from repro.core.layout import (bskiplist_layout, bucket_layout, hash_slot,
                                skiplist_layout, spill_layout, split_u64)
+from repro.kernels.rows import query_tiles
 from repro.kernels.tier_find.kernel import tier_find_tiles
 
 
 def tier_find_fused(hot, cold, spill, queries, *, warm_layout: str = "level",
-                    tile: int = 256, interpret: bool = True):
+                    tile: int | None = None, interpret: bool = True):
     """One dispatch over the whole tier stack. `hot` is a FixedHash,
     `cold` a DetSkiplist, `spill` a SpillTier or None (2-tier stacks).
     Returns ((found, vals, col), (found, vals), (found, vals)) — the same
@@ -29,8 +30,8 @@ def tier_find_fused(hot, cold, spill, queries, *, warm_layout: str = "level",
     B-skiplist planes (`core.layout.bskiplist_layout`) instead of the
     level-major stack — same found/vals, fewer walk steps."""
     t = queries.shape[0]
-    pad = (-t) % tile
-    qp = jnp.pad(queries, (0, pad), constant_values=KEY_INF)
+    tp, tile = query_tiles(t, tile)
+    qp = jnp.pad(queries, (0, tp - t), constant_values=KEY_INF)
     qh, ql = split_u64(qp)
     slots = hash_slot(qp, hot.num_slots)
     blay = bucket_layout(hot.keys)

@@ -1,6 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede every other import — jax locks the device count on first init.
+# a CPU-only tool: on a host with a TPU it must not take the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ both MUST precede every other import — jax locks the device count and
+#   platform on first init.
 
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
 the production meshes, record memory/cost/collective data for §Roofline.
@@ -284,8 +287,6 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
 
         compiled = lowered.compile()
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):   # jax 0.4.x: one dict per device
-            ca = ca[0] if ca else {}
         ma = compiled.memory_analysis()
         report["flops"] = float(ca.get("flops", 0.0))
         report["bytes_accessed"] = float(ca.get("bytes accessed", 0.0))

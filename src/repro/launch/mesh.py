@@ -3,6 +3,19 @@ module never touches jax device state."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """The one mesh builder: every axis `AxisType.Auto`. `jax.make_mesh`
+    defaults to Explicit axes, under which host-side indexing of a sharded
+    state raises `ShardingTypeError` and shard_map bodies see typed
+    sharding; the store and its routing are written for Auto axes.
+    `devices` (optional) pins the mesh to given devices, e.g.
+    `jax.devices()[:1]` for a one-shard engine."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=None if devices is None else list(devices))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -12,10 +25,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     it hierarchically (coarsest first), per the paper's NUMA hierarchy."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    if hasattr(jax.sharding, "AxisType"):      # jax >= 0.5
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)          # 0.4.x: Auto is the default
+    return make_mesh(shape, axes)
 
 
 def dp_axes_of(mesh) -> tuple:

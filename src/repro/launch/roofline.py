@@ -1,5 +1,7 @@
 import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
+# a CPU-only tool: on a host with a TPU it must not take the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 # (must run in the dry-run's 512-device environment)
 
 """Roofline analysis from the compiled dry-run artifacts.

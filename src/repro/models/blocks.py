@@ -89,7 +89,6 @@ def _ffn_apply(p, cfg, x):
 
 def _moe_sharded(p, cfg, x2d, impl: str):
     """Nested shard_map over the model axis (GSPMD auto elsewhere)."""
-    from repro.core.routing import mesh_shard_map
     from jax.sharding import PartitionSpec as P
     from repro.models import moe as moe_mod
     from repro.parallel.sharding import current_mesh, mesh_cfg
@@ -123,8 +122,9 @@ def _moe_sharded(p, cfg, x2d, impl: str):
             return y, _mean_aux(aux)
         in_specs = (_expert_specs(p, tp), P(tuple(dp), None))
         out_specs = (P(tuple(dp), None), P())
-    y2, aux = mesh_shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                        axis_names=manual, check_vma=False)(p, x2d)
+    y2, aux = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, axis_names=manual,
+                            check_vma=False)(p, x2d)
     return y2, aux
 
 

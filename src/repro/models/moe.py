@@ -23,7 +23,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.routing import axis_size, bucketize
+from repro.core.routing import bucketize
 from repro.models.common import cast, dense_init
 
 
@@ -101,7 +101,7 @@ def moe_replicated_psum(p, cfg, x2d, axis: str):
     combine. Runs inside shard_map: p['wi'] etc. arrive [E_local, D, F]."""
     t, d = x2d.shape
     e_local = p["wi"].shape[0]
-    size = axis_size(axis)
+    size = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis).astype(jnp.int32)
     w, idx, aux = router_probs(p, cfg, x2d)      # router replicated
     k = cfg.n_experts_active
@@ -133,7 +133,7 @@ def moe_routed_a2a(p, cfg, x2d, axis: str, capacity_factor: float | None = None)
     if capacity_factor is None:
         capacity_factor = getattr(cfg, "moe_capacity_factor", 2.0)
     e_local = p["wi"].shape[0]
-    size = axis_size(axis)
+    size = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis).astype(jnp.int32)
     w, idx, aux = router_probs(p, cfg, x2d)
     k = cfg.n_experts_active

@@ -14,7 +14,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.routing import axis_size
 
 
 def compress_state_init(params):
@@ -30,7 +29,7 @@ def _quant(g):
 def pod_allreduce_compressed(grads, residuals, axis: str):
     """Per-leaf: g' = mean_pods(dequant(quant(g + residual))); residual
     updated with the local quantization error. Returns (grads', residuals')."""
-    npods = axis_size(axis)
+    npods = jax.lax.axis_size(axis)
 
     def one(g, r):
         g32 = g.astype(jnp.float32) + r
@@ -50,5 +49,5 @@ def pod_allreduce_compressed(grads, residuals, axis: str):
 
 
 def pod_allreduce_plain(grads, axis: str):
-    npods = axis_size(axis)
+    npods = jax.lax.axis_size(axis)
     return jax.tree.map(lambda g: jax.lax.pmean(g, axis), grads)

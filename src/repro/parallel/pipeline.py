@@ -16,7 +16,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from repro.core.routing import mesh_shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -66,7 +65,7 @@ def pipeline_apply(stage_fn, mesh: Mesh, axis: str = "stage"):
         return ys
 
     pspec = jax.tree_util.Partial  # noqa: F841 (doc aid)
-    return mesh_shard_map(body, mesh=mesh,
-                     in_specs=(P(axis), P()),
-                     out_specs=P(),
-                     check_vma=False)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(axis), P()),
+                         out_specs=P(),
+                         check_vma=False)

@@ -35,8 +35,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.routing import (axis_size, mesh_shard_map, route_back,
-                                route_to_owners)
+from repro.core.routing import (route_back, route_to_owners,
+                                shard_linear_id)
+from repro.launch.mesh import make_mesh
 from repro.store import exec as exec_
 from repro.store import obs
 from repro.store.api import OpPlan, Store, get_backend
@@ -118,12 +119,11 @@ def make_store_step(mesh: Mesh, axis_names: Sequence[str], lanes: int,
     # pallas_call has no shard_map replication rule: disable the check ONLY
     # when this backend actually traces one (results unchanged — parity is
     # tested); jnp-fallback backends keep the check in every mode
-    check = False if (mode != "jnp"
-                      and getattr(be, "kernelized", False)) else None
-    step = mesh_shard_map(body, mesh=mesh,
-                          in_specs=(spec1, spec1, spec1, spec1),
-                          out_specs=(spec1, spec1, spec1, spec1),
-                          check_vma=check)
+    check = mode == "jnp" or not getattr(be, "kernelized", False)
+    step = jax.shard_map(body, mesh=mesh,
+                         in_specs=(spec1, spec1, spec1, spec1),
+                         out_specs=(spec1, spec1, spec1, spec1),
+                         check_vma=check)
 
     def wrapped(state, ops, keys, vals):
         st, res, ok, dropped = step(state, ops, keys, vals)
@@ -157,15 +157,13 @@ def make_range_step(mesh: Mesh, axis_names: Sequence[str], lanes: int,
         cnt = jnp.where(vs > 0, cnt, 0)
         for a in axis_names:
             cnt = jax.lax.psum(cnt, a)
-        me = jnp.int32(0)
-        for a in axis_names:
-            me = me * axis_size(a) + jax.lax.axis_index(a).astype(jnp.int32)
-        return jax.lax.dynamic_slice_in_dim(cnt, me * lanes, lanes)
+        return jax.lax.dynamic_slice_in_dim(
+            cnt, shard_linear_id(axis_names) * lanes, lanes)
 
     spec1 = P(tuple(axis_names))
-    return mesh_shard_map(body, mesh=mesh,
-                          in_specs=(spec1, spec1, spec1, spec1),
-                          out_specs=spec1)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(spec1, spec1, spec1, spec1),
+                         out_specs=spec1)
 
 
 def sharded_stats(backend, state) -> dict:
@@ -283,7 +281,6 @@ def local_store_engine(backend: str, lanes: int,
     scheduler/prefix-cache call reuses one traced step per configuration
     (flip modes at trace time by passing `exec_mode`, e.g. from
     `exec.get_mode()` inside an `exec.exec_mode(...)` block)."""
-    mesh = jax.make_mesh((1,), ("local",),
-                         devices=np.array(jax.devices()[:1]))
+    mesh = make_mesh((1,), ("local",), devices=jax.devices()[:1])
     return StoreEngine(mesh, ("local",), lanes, backend=backend,
                        pool_factor=1, exec_mode=exec_mode)

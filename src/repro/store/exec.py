@@ -6,10 +6,14 @@ interchangeable modes, selected by config string
 (`configs/*.py: store_exec`, or the `REPRO_STORE_EXEC` env default):
 
   jnp        pure-jnp reference probes (`core.det_skiplist.find_batch`,
-             `core.hashtable.fixed_find`, ...) — the portable baseline
+             `core.hashtable.fixed_find`, ...) — the portable baseline and
+             the default
   interpret  Pallas kernels in interpreter mode — the kernel bodies execute
              on CPU; what CI runs
-  pallas     Pallas kernels compiled (TPU) — the production hot path
+  pallas     Pallas kernels compiled for the TPU. Every store kernel
+             compiles for v5e (tests/test_chip_compile.py) and matches jnp
+             bit for bit on one v5e chip (chip_smoke.py); whether it is
+             faster than jnp there has not been measured
 
 The correctness contract is BIT-IDENTICAL results across all three modes
 for every backend (asserted by tests/test_exec_modes.py): the kernels
@@ -22,18 +26,20 @@ Kernelized probes: the deterministic skiplist search
 `kernels.bskiplist_walk`, dispatched by `bskiplist_find` — lane-width fat
 nodes, one whole-block compare per step), the fixed-hash bucket probe
 (`kernels.hash_probe` — also the §IX hot-tier fast path), the FUSED
-tier-stack find (`kernels.tier_find` — hot probe + warm walk + per-run
-spill search in ONE pallas_call, dispatched by `tier_find`), the
-two-level split-order per-table searchsorted (`kernels.splitorder_probe`),
-and the priority-queue pop rank-select (`kernels.pq_pop`, dispatched by
-`pq_pop` — live-prefix cumsum + the shared `level_walk` descent).
-Probes whose access pattern defeats the static-shape or VMEM premise (the
-randomized skiplist's MAX_GAP-padded walk, ONE-level split-order's
-searchsorted over the full array — the global array does not fit VMEM,
-which is why only the two-level variant kernelizes — and the two-level
-hash table's pooled L2 indirection) fall back to their jnp reference in
-every mode — still routed through this module so a future kernel is a
-one-function change.
+tier-stack find and apply (`kernels.tier_find`, `kernels.tier_apply`),
+the two-level split-order per-table searchsorted
+(`kernels.splitorder_probe`), and the priority-queue pop rank-select
+(`kernels.pq_pop`, dispatched by `pq_pop` — live-prefix binary search +
+the shared `level_walk` descent). Probes whose access pattern defeats the
+static-shape or VMEM premise (the randomized skiplist's MAX_GAP-padded
+walk, ONE-level split-order's searchsorted over the full array — the
+global array does not fit VMEM, which is why only the two-level variant
+kernelizes — and the two-level hash table's pooled L2 indirection), and
+the unfused chain's standalone spill probe and hot-tier update, have no
+kernel: they run their jnp reference in the jnp and interpret modes and
+REFUSE the pallas mode, so a pallas request never runs a reference in
+silence. They are still routed through this module so a future kernel is
+a one-function change.
 
 The mode is read at TRACE time: `StoreEngine`/`make_store_step` bake it
 into the jitted step via `exec_mode(...)`, so two engines with different
@@ -103,34 +109,58 @@ def _resolve(mode: str | None) -> str:
     return _check(mode) if mode is not None else _mode
 
 
+def _reference_only(name: str, mode: str | None) -> None:
+    """Gate of the probes that have no kernel: they refuse `pallas`."""
+    if _resolve(mode) == "pallas":
+        raise NotImplementedError(
+            f"store.exec.{name} has no Pallas kernel; exec mode 'pallas' "
+            f"would run its jnp reference — use exec mode 'jnp'")
+
+
 _PALLAS_OK: bool | None = None
 
 
 def pallas_available() -> bool:
-    """True iff COMPILED Pallas kernels run on the current jax backend
-    (TPU; CPU/GPU get interpret mode only). Probed once with a tiny kernel
-    launch; tests and benchmarks use this to scope the `pallas` mode."""
+    """True iff COMPILED Pallas kernels run on the current jax backend.
+    Off TPU (CPU/GPU get interpret mode only) this is False. On a TPU it
+    launches a real store kernel — the hash probe over one full query tile
+    — checks it against the jnp reference, and returns True; a kernel that
+    fails to compile, run or match RAISES there, since a TPU that cannot
+    run the kernels is a fault to see, not a mode to drop. Checked once
+    per process; tests and benchmarks use it to scope the `pallas` mode."""
     global _PALLAS_OK
     if _PALLAS_OK is None:
-        try:
-            import jax
-            import jax.numpy as jnp
-            from repro.kernels.hash_probe.kernel import hash_probe_tiles
-            z32 = jnp.zeros((8,), jnp.uint32)
-            out = hash_probe_tiles(z32, z32, z32.astype(jnp.int32),
-                                   jnp.zeros((4, 8), jnp.uint32),
-                                   jnp.zeros((4, 8), jnp.uint32),
-                                   tile=8, interpret=False)
-            jax.block_until_ready(out)
-            _PALLAS_OK = True
-        except Exception:
+        import jax
+        if jax.default_backend() != "tpu":
             _PALLAS_OK = False
+        else:
+            _check_pallas_kernel()
+            _PALLAS_OK = True
     return _PALLAS_OK
 
 
+def _check_pallas_kernel() -> None:
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.core import hashtable as ht
+    from repro.kernels.hash_probe.ops import fixed_hash_find_cols
+    from repro.kernels.rows import MAX_TILE
+    keys = jnp.arange(1, 2 * MAX_TILE + 1, 2, dtype=jnp.uint64)
+    h, _, _ = ht.fixed_insert(ht.fixed_init(256, 8), keys[: MAX_TILE // 2],
+                              keys[: MAX_TILE // 2])
+    got = fixed_hash_find_cols(h, keys, interpret=False)
+    want = ht.fixed_find_cols(h, keys)
+    for g, w in zip(got, want):
+        if not np.array_equal(np.asarray(g), np.asarray(w)):
+            raise RuntimeError("compiled hash_probe kernel disagrees with "
+                               "its jnp reference on this TPU")
+
+
 def runnable_modes() -> tuple:
-    """The execution modes that can actually run here (drops `pallas` off
-    TPU) — what parity tests and benchmarks iterate over."""
+    """The execution modes that can actually run here — all three on a TPU
+    (`pallas_available` raises there rather than drop one), `jnp` and
+    `interpret` elsewhere. What parity tests and benchmarks iterate
+    over."""
     return MODES if pallas_available() else tuple(m for m in MODES
                                                   if m != "pallas")
 
@@ -353,27 +383,30 @@ def hash_find_cols(h, queries, mode: str | None = None):
 
 @_probe
 def rand_skiplist_find(s, queries, mode: str | None = None):
-    """Randomized-skiplist FIND — jnp in every mode (the MAX_GAP-padded walk
-    has no static-shape kernel win; see docs/store_layers.md)."""
-    _resolve(mode)
+    """Randomized-skiplist FIND — jnp only, refuses pallas (the
+    MAX_GAP-padded walk has no static-shape kernel win; see
+    docs/store_layers.md)."""
+    _reference_only("rand_skiplist_find", mode)
     from repro.core import rand_skiplist as rsl
     return rsl.find_batch(s, queries)
 
 
 @_probe
 def twolevel_hash_find(h, queries, mode: str | None = None):
-    """Two-level hash FIND — jnp in every mode (pooled L2 indirection)."""
-    _resolve(mode)
+    """Two-level hash FIND — jnp only, refuses pallas (pooled L2
+    indirection)."""
+    _reference_only("twolevel_hash_find", mode)
     from repro.core import hashtable as ht
     return ht.twolevel_find(h, queries)
 
 
 @_probe
 def splitorder_find(h, queries, mode: str | None = None):
-    """ONE-level split-order FIND — jnp in every mode: its searchsorted
-    runs over the single global [C] array, which does not fit VMEM at
-    production capacity (the two-level variant is the kernelized one)."""
-    _resolve(mode)
+    """ONE-level split-order FIND — jnp only, refuses pallas: its
+    searchsorted runs over the single global [C] array, which does not fit
+    VMEM at production capacity (the two-level variant is the kernelized
+    one)."""
+    _reference_only("splitorder_find", mode)
     from repro.core import splitorder as so
     return so.splitorder_find(h, queries)
 
@@ -394,14 +427,14 @@ def twolevel_splitorder_find(h, queries, mode: str | None = None):
 
 @_probe
 def spill_find(sp, queries, mode: str | None = None):
-    """Cold spill-tier membership probe: (found[Q], vals[Q]). jnp in every
-    mode — since the fused tier find, a per-run binary search over the
+    """Cold spill-tier membership probe: (found[Q], vals[Q]). jnp only,
+    refuses pallas — since the fused tier find, a per-run binary search over the
     `run_offsets` boundaries (`kernels.tier_find.ref.spill_find_runs`,
     O(runs * log run-len); the old flat masked compare is gone from every
     path). Standalone spill probes only run on the UNFUSED chain — the
     fused path folds this search into the single `tier_find` dispatch —
     so the cold tier keeps no dedicated kernel of its own."""
-    _resolve(mode)
+    _reference_only("spill_find", mode)
     from repro.store.tiers import spill_find_ref
     return spill_find_ref(sp, queries)
 
@@ -451,14 +484,14 @@ def tier_find(hot, cold, spill, queries, mode: str | None = None,
 def hot_update(hot, meta, clock, keys, vals, mask, policy, max_evict,
                mode: str | None = None):
     """Hot-tier insert prologue as ONE counted update dispatch — the
-    UNFUSED write path (membership probes already ran). jnp in every mode:
-    the sort/scatter prologue (`bucket_insert_plan` + victim selection) is
-    gather/scatter-bound with no kernel win of its own; the fused
-    `tier_apply` is the kernelized form. Returns
+    UNFUSED write path (membership probes already ran). jnp only, refuses
+    pallas: the sort/scatter prologue (`bucket_insert_plan` + victim
+    selection) is gather/scatter-bound with no kernel win of its own; the
+    fused `tier_apply` is the kernelized form. Returns
     (hot', meta', ins[K], exists[K], ev_key[K], ev_val[K], ev_mask[K]);
     for `policy == "none"` the eviction lanes are all-miss zeros and meta
     passes through unchanged."""
-    _resolve(mode)
+    _reference_only("hot_update", mode)
     import jax.numpy as jnp
     from repro.kernels.tier_apply.ref import hot_insert_evict
     if policy == "none":

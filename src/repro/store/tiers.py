@@ -15,17 +15,16 @@ Tier layout (every live key resides in EXACTLY ONE tier):
          a per-entry policy-metadata plane (`core.layout.policy_arrays`)
   warm   the deterministic skiplist (ordered, large — the `cold` field,
          named for continuity with the two-tier stack)
-  cold   `SpillTier` (depth-3 only): append-only sorted runs outside the
-         hot/warm device-resident structures (`core.layout.spill_arrays`;
-         on TPU the planes are placed in pinned host memory — see
-         `_pin_spill_host`). Cells below the cursor are immutable except
-         for tombstones, so the region can live in host/pinned memory and
-         be DMA'd in bulk; runs are merged on scan, probed by a per-run
-         binary search over the `core.layout.run_offsets` boundary plane,
-         and `spill_compact` rewrites them (dropping tombstones) when dead
-         entries pass 1/4 of the appended total OR the live run count
-         nears `core.layout.MAX_SPILL_RUNS` (the static cap that keeps the
-         probe's boundary plane fixed-size).
+  cold   `SpillTier` (depth-3 only): append-only sorted runs next to the
+         hot/warm structures (`core.layout.spill_arrays`; device-resident
+         like every other state leaf). Cells below the cursor are
+         immutable except for tombstones, so the region could live in
+         host memory and be DMA'd in bulk; runs are merged on scan, probed
+         by a per-run binary search over the `core.layout.run_offsets`
+         boundary plane, and `spill_compact` rewrites them (dropping
+         tombstones) when dead entries pass 1/4 of the appended total OR
+         the live run count nears `core.layout.MAX_SPILL_RUNS` (the static
+         cap that keeps the probe's boundary plane fixed-size).
 
 Probe execution (the `fused` knob, default True): the FIND phases issue
 ONE `store.exec.tier_find` dispatch per plan — the fused
@@ -202,7 +201,7 @@ def spill_compact(sp: SpillTier) -> SpillTier:
         vals=jnp.where(live, sp.vals, jnp.uint64(0))[o],
         dead=jnp.zeros_like(sp.dead),
         run_start=jnp.zeros_like(sp.run_start).at[0].set(n_live > 0),
-        n=n_live, n_dead=jnp.int32(0))
+        n=n_live, n_dead=jnp.zeros_like(sp.n_dead))
 
 
 def spill_discard(sp: SpillTier, keys, mask):
@@ -246,25 +245,6 @@ def spill_maintain(sp: SpillTier) -> SpillTier:
     return jax.lax.cond(
         churn | (runs + SpillLayout.RUNS_PER_APPLY > SpillLayout.MAX_RUNS),
         spill_compact, lambda s: s, sp)
-
-
-def _pin_spill_host(sp: SpillTier) -> SpillTier:
-    """Best-effort placement of the spill planes in pinned host memory —
-    the append-only layout was built for exactly this (cells below the
-    cursor move only in bulk). Only attempted on TPU backends that expose
-    a `pinned_host` memory space; anywhere else (CPU CI, older runtimes)
-    it is a guarded no-op. Engines that re-device_put the whole state with
-    their own sharding override the placement — this covers the direct
-    single-device path."""
-    try:
-        dev = jax.devices()[0]
-        if dev.platform != "tpu":
-            return sp
-        sharding = jax.sharding.SingleDeviceSharding(
-            dev, memory_kind="pinned_host")
-        return jax.tree.map(lambda x: jax.device_put(x, sharding), sp)
-    except Exception:
-        return sp
 
 
 class TierState(NamedTuple):
@@ -321,8 +301,7 @@ class TieredBackend:
             n_evict=jnp.int64(0),
             n_promote=jnp.int64(0),
             cold=dsl.skiplist_init(capacity),
-            spill=(_pin_spill_host(
-                spill_init(capacity if spill_cap is None else spill_cap))
+            spill=(spill_init(capacity if spill_cap is None else spill_cap)
                    if self.depth == 3 else None))
 
     # -- tier movement helpers ----------------------------------------------

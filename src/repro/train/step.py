@@ -84,28 +84,13 @@ def make_train_step(cfg, *, lr_peak=3e-4, warmup=100, total_steps=10000,
         return train_step
 
     assert mesh is not None and pod_axis in mesh.axis_names
-    from repro.core.routing import mesh_shard_map
     from jax.sharding import PartitionSpec as P
 
-    try:                       # partial-manual (intra-pod axes on GSPMD auto)
-        from jax import shard_map as _new_sm  # noqa: F401
-        partial_manual = True
-    except ImportError:
-        # jax 0.4.x: all_gather inside a partial-manual region aborts XLA's
-        # SPMD partitioner, so go FULLY manual: intra-pod axes exchange
-        # gradients with an explicit uncompressed pmean (the fast ICI hop),
-        # then the pod (DCI) hop runs the int8 exchange as before
-        partial_manual = False
-    intra_axes = tuple(a for a in mesh.axis_names if a != pod_axis)
-
     def train_step(params, opt_state, batch):
+        # partial-manual: only the pod axis is manual, intra-pod axes stay
+        # on GSPMD auto
         def per_pod(params, residuals, batch):
             grads, loss, aux = grads_of(params, cfg, batch, microbatches)
-            if not partial_manual:
-                for ax in intra_axes:
-                    grads = jax.tree.map(lambda g: jax.lax.pmean(g, ax), grads)
-                    loss = jax.lax.pmean(loss, ax)
-                    aux = jax.lax.pmean(aux, ax)
             grads, residuals = pod_allreduce_compressed(grads, residuals,
                                                         pod_axis)
             loss = jax.lax.pmean(loss, pod_axis)
@@ -113,18 +98,12 @@ def make_train_step(cfg, *, lr_peak=3e-4, warmup=100, total_steps=10000,
             return grads, residuals, loss, aux
 
         specs_p = jax.tree.map(lambda _: P(), params)
-        if partial_manual:
-            batch_specs = jax.tree.map(lambda _: P(pod_axis), batch)
-            manual_kw = dict(axis_names={pod_axis}, check_vma=False)
-        else:
-            batch_specs = jax.tree.map(lambda _: P(tuple(mesh.axis_names)),
-                                       batch)
-            manual_kw = dict(check_vma=False)
-        grads, residuals, loss, aux = mesh_shard_map(
+        batch_specs = jax.tree.map(lambda _: P(pod_axis), batch)
+        grads, residuals, loss, aux = jax.shard_map(
             per_pod, mesh=mesh,
             in_specs=(specs_p, specs_p, batch_specs),
             out_specs=(specs_p, specs_p, P(), P()),
-            **manual_kw,
+            axis_names={pod_axis}, check_vma=False,
         )(params, opt_state["residuals"], batch)
         params, opt_state, metrics = apply_update(params, opt_state, grads,
                                                   loss, aux)

@@ -18,6 +18,7 @@ import repro  # noqa: F401,E402
 from repro.configs import get_reduced  # noqa: E402
 from repro.configs.base import ShapeConfig  # noqa: E402
 from repro.data.pipeline import synth_batch  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import model as M  # noqa: E402
 from repro.optim.adamw import adamw_init  # noqa: E402
 from repro.optim.compress import compress_state_init  # noqa: E402
@@ -26,7 +27,7 @@ from repro.train.step import make_train_step  # noqa: E402
 
 def main() -> int:
     cfg = get_reduced("qwen3-1.7b")
-    mesh = jax.make_mesh((2, 4), ("pod", "data"))
+    mesh = make_mesh((2, 4), ("pod", "data"))
     shape = ShapeConfig("t", seq_len=32, global_batch=8, kind="train")
     params = M.init_params(jax.random.PRNGKey(0), cfg)
 

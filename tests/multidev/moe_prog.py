@@ -15,6 +15,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
 
 import repro  # noqa: F401,E402
 from repro.configs import get_reduced  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import moe as moe_mod  # noqa: E402
 from repro.models.blocks import _moe_sharded  # noqa: E402
 from repro.parallel.sharding import use_mesh  # noqa: E402
@@ -22,7 +23,7 @@ from repro.parallel.sharding import use_mesh  # noqa: E402
 
 def main() -> int:
     cfg = get_reduced("qwen3-moe-235b-a22b")  # 8 experts top-2
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     key = jax.random.PRNGKey(0)
     p = moe_mod.init_moe(key, cfg)
     t, d = 64, cfg.d_model

@@ -12,12 +12,12 @@ import jax.numpy as jnp  # noqa: E402
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
 
 import repro  # noqa: F401,E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.parallel.pipeline import pipeline_apply  # noqa: E402
 
 
 def main() -> int:
-    mesh = jax.make_mesh((4,), ("stage",),
-                         devices=np.asarray(jax.devices()[:4]))
+    mesh = make_mesh((4,), ("stage",), devices=jax.devices()[:4])
     S, M, B, D = 4, 6, 2, 16
     rng = np.random.default_rng(0)
     w = jnp.asarray(rng.standard_normal((S, D, D)) / np.sqrt(D), jnp.float32)

@@ -21,6 +21,7 @@ import jax.numpy as jnp  # noqa: E402
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
 
 import repro  # noqa: F401,E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.store import (OP_DELETE, OP_FIND, OP_INSERT, OP_POPK,  # noqa: E402
                          OP_POPMIN)
 from repro.store.engine import StoreEngine  # noqa: E402
@@ -691,7 +692,7 @@ def check_recover(mesh, backend: str = "obs:tiered3/lru") -> None:
 
 
 def main() -> int:
-    mesh = jax.make_mesh((2, 4), AXES)
+    mesh = make_mesh((2, 4), AXES)
     for backend in BACKENDS:
         check_backend(mesh, backend)
     for backend in ("det_skiplist", "hash+skiplist"):

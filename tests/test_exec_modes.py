@@ -149,9 +149,9 @@ def test_engine_exec_mode_single_device():
     """StoreEngine bakes the mode into its jitted step; results match the
     jnp engine bit-for-bit on a 1-device mesh (8-device parity runs in
     tests/multidev/store_prog.py)."""
+    from repro.launch.mesh import make_mesh
     from repro.store.engine import StoreEngine
-    mesh = jax.make_mesh((1,), ("data",),
-                         devices=np.array(jax.devices()[:1]))
+    mesh = make_mesh((1,), ("data",), devices=jax.devices()[:1])
     rng = np.random.default_rng(3)
     keys = rng.integers(1, 2**63, 32, dtype=np.uint64)
     ops = rng.choice([OP_FIND, OP_INSERT, OP_DELETE], 32,

@@ -197,11 +197,11 @@ class TestMetricsParity:
         # METRICS-OK, 1-device form (8-shard form in multidev/store_prog.py):
         # the engine-carried plane == a direct observed instance replaying
         # the same stream, plus exact routed_ops/routed_bytes
-        from jax.sharding import Mesh
+        from repro.launch.mesh import make_mesh
         from repro.store.engine import StoreEngine
 
         lanes, steps = 16, 5
-        mesh = Mesh(np.array(jax.devices()[:1]), ("d",))
+        mesh = make_mesh((1,), ("d",), devices=jax.devices()[:1])
         eng = StoreEngine(mesh, ("d",), lanes=lanes,
                           backend="obs:tiered3/lru")
         state = jax.device_put(eng.init(64, hot_bucket=4, hot_frac=8),

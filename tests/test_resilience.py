@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 import repro  # noqa: F401
+from repro.launch.mesh import make_mesh
 from repro.store import engine as engine_mod
 from repro.store import obs
 from repro.store import resilience as R
@@ -31,8 +32,7 @@ CAP = 256
 def _mk_engine(exec_mode=None, lanes=LANES):
     """A FRESH 1-shard engine (not the lru-cached local engine: these tests
     own the host `seq` counter)."""
-    mesh = jax.make_mesh((1,), ("local",),
-                         devices=np.array(jax.devices()[:1]))
+    mesh = make_mesh((1,), ("local",), devices=jax.devices()[:1])
     return engine_mod.StoreEngine(mesh, ("local",), lanes, backend=BACKEND,
                                   pool_factor=1, exec_mode=exec_mode)
 

@@ -2,14 +2,14 @@
 
 The fused `store.exec.tier_apply` (kernels/tier_apply — the tier_find
 membership probes + the hot-insert linearization + the eviction policy's
-victim selection in ONE pallas_call, spill planes streamed through VMEM
-chunks under a scalar-prefetched `run_offsets` plane) must be
+victim selection in ONE pallas_call, lanes scanned in tiles with the
+running counts carried across them) must be
 BIT-IDENTICAL to the jnp reference and to the unfused dispatch-per-phase
 chain, for results AND the full residency pytree, in every runnable exec
 mode. Covered here: direct exec-entry parity across modes for every
 policy, the measured dispatch budget (a whole fused apply = exactly TWO
-dispatches: one tier_apply update + one FIND-phase tier_find probe), the
-spill-chunk streaming path against oversized spill tiers, run-cap
+dispatches: one tier_apply update + one FIND-phase tier_find probe), a
+many-run spill tier scanned in several lane tiles, run-cap
 compaction tripping INSIDE a fused apply, and the empty batch. (The
 8-device engine analogue runs in tests/multidev/store_prog.py: APPLY-OK.)
 """
@@ -92,7 +92,7 @@ def test_tier_apply_exec_matches_ref_across_modes(name):
 
 def test_tier_apply_two_tier_stack_no_spill():
     """spill=None (hash+skiplist depth): the kernel builds without the
-    scalar-prefetched chunk grid and spill lanes are all-miss."""
+    spill planes and spill lanes are all-miss."""
     _, st, ks = _loaded_state("hash+skiplist")
     assert st.spill is None
     keys, vals, mask = _apply_batch(ks, seed=11)
@@ -107,10 +107,10 @@ def test_tier_apply_two_tier_stack_no_spill():
     assert not np.asarray(ref[3]).any()             # in_spill all-miss
 
 
-def test_tier_apply_streams_spill_in_chunks():
-    """A spill tier larger than one chunk exercises the scalar-prefetched
-    grid: per-chunk window clipping + the VMEM OR-accumulator must
-    reproduce the global searchsorted bit exactly."""
+def test_tier_apply_large_spill_across_lane_tiles():
+    """A many-run spill tier probed by a plan cut into several lane tiles:
+    the per-run spill search and the running counts carried from tile to
+    tile must reproduce the reference bit exactly."""
     from repro.kernels.tier_apply.ops import tier_apply_fused
     from repro.kernels.tier_apply.ref import tier_apply_ref
     be = get_backend("tiered3/lru")
@@ -120,14 +120,14 @@ def test_tier_apply_streams_spill_in_chunks():
     for chunk in np.array_split(ks, 4):
         st, _ = be.apply(st, make_plan(
             np.full(len(chunk), OP_INSERT, np.int32), chunk, chunk + 1))
-    assert int(st.spill.n) > 256                  # multiple 128-wide chunks
+    assert int(st.spill.n) > 256                  # several spill rows
     keys, vals, mask = _apply_batch(ks, seed=23)
     ref = tier_apply_ref(st.hot, st.hot_meta, st.clock, st.cold, st.spill,
                          keys, vals, mask, "lru", 8)
     got = tier_apply_fused(st.hot, st.hot_meta, st.clock, st.cold, st.spill,
-                           keys, vals, mask, "lru", 8, spill_chunk=128,
+                           keys, vals, mask, "lru", 8, tile=16,
                            interpret=True)
-    assert_states_equal(ref, got, "chunked-spill")
+    assert_states_equal(ref, got, "tiled-lanes")
     assert np.asarray(ref[3]).any()               # spill residents probed
 
 

@@ -143,13 +143,15 @@ def test_run_count_stays_under_cap():
     assert int(be.stats(st)["spill_size"]) > 0
 
 
-def test_pinned_host_guard_is_noop_off_tpu():
-    from repro.store.tiers import _pin_spill_host
-    sp = spill_init(32)
-    sp2 = _pin_spill_host(sp)
-    if jax.default_backend() != "tpu":
-        assert sp2 is sp                      # guarded no-op on CPU CI
-    assert_states_equal(sp, sp2, "pin")
+def test_spill_tier_init_is_device_resident():
+    """The spill planes are placed like every other state leaf (on the
+    default device); no init path moves them to host memory."""
+    st = get_backend("tiered3").init(32, hot_bucket=4, hot_frac=8)
+    assert_states_equal(st.spill, spill_init(32), "spill init")
+    dev = jax.devices()[0]
+    for leaf in jax.tree.leaves(st.spill):
+        assert leaf.devices() == {dev}
+        assert leaf.sharding.memory_kind in (None, dev.default_memory().kind)
 
 
 # ---------------------------------------------------------------------------

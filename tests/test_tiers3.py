@@ -363,9 +363,10 @@ def test_engine_residency_matches_direct_apply(name):
     is bit-identical to a direct (engine-less) instance applying the same
     per-round op multisets — placement depends on sorted key order, not
     lane order, so the engine's routing/pooling cannot change residency."""
+    from repro.launch.mesh import make_mesh
     from repro.store.engine import StoreEngine
     lanes = 32
-    mesh = jax.make_mesh((1,), ("data",), devices=np.array(jax.devices()[:1]))
+    mesh = make_mesh((1,), ("data",), devices=jax.devices()[:1])
     eng = StoreEngine(mesh, ("data",), lanes, backend=name)
     state = jax.device_put(eng.init(64, hot_bucket=4, hot_frac=8),
                            eng.sharding)

@@ -71,12 +71,12 @@ def record_demo_trace(backend: str = "obs:tiered3/lru", steps: int = 8,
     import numpy as np
     import jax
     import jax.numpy as jnp
-    from jax.sharding import Mesh
+    from repro.launch.mesh import make_mesh
     from repro.store import obs
     from repro.store import resilience as R
     from repro.store.engine import StoreEngine
 
-    mesh = Mesh(np.array(jax.devices()[:1]), ("d",))
+    mesh = make_mesh((1,), ("d",), devices=jax.devices()[:1])
     eng = StoreEngine(mesh, ("d",), lanes=lanes, backend=backend)
     drive = eng
     if fault_step is not None:
