@@ -31,6 +31,12 @@ TPU adaptation (DESIGN.md §4): pointers -> level-major sorted arrays.
   sentinels = never out of bounds).
 
 All ops are jit-able; state is a pytree (checkpointable for free).
+
+Device phases: each write pass opens a `jax.named_scope("obs.<name>")`
+— "merge", "rebuild", "tombstone", "range_delete", "reclaim" — so a
+`jax.profiler` trace can attribute its device time. The names belong to
+`repro.store.obs.DEVICE_PHASES`; this module opens the scopes directly
+because `core` does not import `repro.store`.
 """
 from __future__ import annotations
 
@@ -118,6 +124,7 @@ def _group(n_prev: jnp.ndarray, cap_l: int, prev_keys: jnp.ndarray):
     return keys, child, g
 
 
+@jax.named_scope("obs.rebuild")
 def _rebuild_levels(s: DetSkiplist) -> DetSkiplist:
     """Rebuild every index level from the terminal array (work n/3^l at level
     l — the geometric decay of eqs. 2-4, amortized over the batch)."""
@@ -230,63 +237,63 @@ def insert_batch(s: DetSkiplist, keys: jnp.ndarray, vals: jnp.ndarray,
         mask = jnp.ones((K,), bool)
     mask = mask & (keys != KEY_INF)
 
-    order = jnp.argsort(keys, stable=True)
-    sk = keys[order]
-    sv = vals[order]
-    sm = mask[order]
-    same = jnp.concatenate([jnp.zeros((1,), bool), sk[1:] == sk[:-1]])
-    dup = dup_in_run(same, sm)
+    with jax.named_scope("obs.merge"):
+        order = jnp.argsort(keys, stable=True)
+        sk = keys[order]
+        sv = vals[order]
+        sm = mask[order]
+        same = jnp.concatenate([jnp.zeros((1,), bool), sk[1:] == sk[:-1]])
+        dup = dup_in_run(same, sm)
 
-    pos = jnp.searchsorted(s.term_keys, sk).astype(jnp.int32)
-    posc = jnp.clip(pos, 0, C - 1)
-    match = sm & (pos < C) & (s.term_keys[posc] == sk)
-    revive = match & s.term_mark[posc] & ~dup
-    exists = match & ~s.term_mark[posc]
+        pos = jnp.searchsorted(s.term_keys, sk).astype(jnp.int32)
+        posc = jnp.clip(pos, 0, C - 1)
+        match = sm & (pos < C) & (s.term_keys[posc] == sk)
+        revive = match & s.term_mark[posc] & ~dup
+        exists = match & ~s.term_mark[posc]
 
-    # revive in place (first lane among in-batch dups wins — dup already
-    # false); a revival is a re-insertion, so its snapshot stamp refreshes
-    # to the current batch clock (upserts on LIVE entries do not re-stamp)
-    rpos = jnp.where(revive, posc, C)
-    term_mark = s.term_mark.at[rpos].set(False, mode="drop")
-    term_vals = s.term_vals.at[rpos].set(sv, mode="drop")
-    term_stamp = s.term_stamp.at[rpos].set(s.clock, mode="drop")
-    n_marked = s.n_marked - jnp.sum(revive).astype(jnp.int32)
+        # revive in place (first lane among in-batch dups wins — dup already
+        # false); a revival is a re-insertion, so its snapshot stamp refreshes
+        # to the current batch clock (upserts on LIVE entries do not re-stamp)
+        rpos = jnp.where(revive, posc, C)
+        term_mark = s.term_mark.at[rpos].set(False, mode="drop")
+        term_vals = s.term_vals.at[rpos].set(sv, mode="drop")
+        term_stamp = s.term_stamp.at[rpos].set(s.clock, mode="drop")
+        n_marked = s.n_marked - jnp.sum(revive).astype(jnp.int32)
 
-    new = sm & ~match & ~dup
-    rank = jnp.cumsum(new.astype(jnp.int32)) - 1
-    new = new & (s.n_term + rank < C)                      # overflow -> fail lanes
-    n_new = jnp.sum(new).astype(jnp.int32)
+        new = sm & ~match & ~dup
+        rank = jnp.cumsum(new.astype(jnp.int32)) - 1
+        new = new & (s.n_term + rank < C)                      # overflow -> fail lanes
+        n_new = jnp.sum(new).astype(jnp.int32)
 
-    # compact the new keys into a sorted [K] buffer (pad KEY_INF)
-    crank = jnp.where(new, rank, K)
-    newk = jnp.full((K,), KEY_INF).at[crank].set(sk, mode="drop")
-    newv = jnp.zeros((K,), jnp.uint64).at[crank].set(sv, mode="drop")
+        # compact the new keys into a sorted [K] buffer (pad KEY_INF)
+        crank = jnp.where(new, rank, K)
+        newk = jnp.full((K,), KEY_INF).at[crank].set(sk, mode="drop")
+        newv = jnp.zeros((K,), jnp.uint64).at[crank].set(sv, mode="drop")
 
-    # two-way sorted merge by destination scatter
-    old_idx = jnp.arange(C, dtype=jnp.int32)
-    dest_old = old_idx + jnp.searchsorted(newk, s.term_keys, side="left").astype(jnp.int32)
-    dest_old = jnp.where(old_idx < s.n_term, dest_old, C)
-    dest_new = (jnp.searchsorted(s.term_keys, newk, side="left").astype(jnp.int32)
-                + jnp.arange(K, dtype=jnp.int32))
-    dest_new = jnp.where(jnp.arange(K) < n_new, dest_new, C)
+        # two-way sorted merge by destination scatter
+        old_idx = jnp.arange(C, dtype=jnp.int32)
+        dest_old = old_idx + jnp.searchsorted(newk, s.term_keys, side="left").astype(jnp.int32)
+        dest_old = jnp.where(old_idx < s.n_term, dest_old, C)
+        dest_new = (jnp.searchsorted(s.term_keys, newk, side="left").astype(jnp.int32)
+                    + jnp.arange(K, dtype=jnp.int32))
+        dest_new = jnp.where(jnp.arange(K) < n_new, dest_new, C)
 
-    tk = jnp.full((C,), KEY_INF).at[dest_old].set(s.term_keys, mode="drop")
-    tk = tk.at[dest_new].set(newk, mode="drop")
-    tv = jnp.zeros((C,), jnp.uint64).at[dest_old].set(term_vals, mode="drop")
-    tv = tv.at[dest_new].set(newv, mode="drop")
-    tm = jnp.zeros((C,), bool).at[dest_old].set(term_mark, mode="drop")
-    # new entries unmarked (already False); their stamp = this batch's clock
-    ts = jnp.zeros((C,), jnp.int32).at[dest_old].set(term_stamp, mode="drop")
-    ts = ts.at[dest_new].set(s.clock, mode="drop")
+        tk = jnp.full((C,), KEY_INF).at[dest_old].set(s.term_keys, mode="drop")
+        tk = tk.at[dest_new].set(newk, mode="drop")
+        tv = jnp.zeros((C,), jnp.uint64).at[dest_old].set(term_vals, mode="drop")
+        tv = tv.at[dest_new].set(newv, mode="drop")
+        tm = jnp.zeros((C,), bool).at[dest_old].set(term_mark, mode="drop")
+        # new entries unmarked (already False); their stamp = this batch's clock
+        ts = jnp.zeros((C,), jnp.int32).at[dest_old].set(term_stamp, mode="drop")
+        ts = ts.at[dest_new].set(s.clock, mode="drop")
 
-    s2 = s._replace(term_keys=tk, term_vals=tv, term_mark=tm, term_stamp=ts,
-                    n_term=s.n_term + n_new, n_marked=n_marked)
-    s2 = _rebuild_levels(s2)
+        s2 = s._replace(term_keys=tk, term_vals=tv, term_mark=tm, term_stamp=ts,
+                        n_term=s.n_term + n_new, n_marked=n_marked)
 
-    inv = jnp.zeros((K,), jnp.int32).at[order].set(jnp.arange(K, dtype=jnp.int32))
-    inserted = (new | revive)[inv]
-    existed = (exists | dup)[inv]
-    return s2, inserted, existed
+        inv = jnp.zeros((K,), jnp.int32).at[order].set(jnp.arange(K, dtype=jnp.int32))
+        inserted = (new | revive)[inv]
+        existed = (exists | dup)[inv]
+    return _rebuild_levels(s2), inserted, existed
 
 
 # ---------------------------------------------------------------------------
@@ -305,27 +312,28 @@ def delete_batch(s: DetSkiplist, keys: jnp.ndarray,
     if mask is None:
         mask = jnp.ones((K,), bool)
 
-    order = jnp.argsort(keys, stable=True)
-    sk = keys[order]
-    sm = mask[order] & (sk != KEY_INF)
-    same = jnp.concatenate([jnp.zeros((1,), bool), sk[1:] == sk[:-1]])
-    dup = dup_in_run(same, sm)
+    with jax.named_scope("obs.tombstone"):
+        order = jnp.argsort(keys, stable=True)
+        sk = keys[order]
+        sm = mask[order] & (sk != KEY_INF)
+        same = jnp.concatenate([jnp.zeros((1,), bool), sk[1:] == sk[:-1]])
+        dup = dup_in_run(same, sm)
 
-    pos = jnp.searchsorted(s.term_keys, sk).astype(jnp.int32)
-    posc = jnp.clip(pos, 0, C - 1)
-    hit = sm & ~dup & (pos < C) & (s.term_keys[posc] == sk) & ~s.term_mark[posc]
+        pos = jnp.searchsorted(s.term_keys, sk).astype(jnp.int32)
+        posc = jnp.clip(pos, 0, C - 1)
+        hit = sm & ~dup & (pos < C) & (s.term_keys[posc] == sk) & ~s.term_mark[posc]
 
-    mark = s.term_mark.at[jnp.where(hit, posc, C)].set(True, mode="drop")
-    n_marked = s.n_marked + jnp.sum(hit).astype(jnp.int32)
-    s2 = s._replace(term_mark=mark, n_marked=n_marked)
-
+        mark = s.term_mark.at[jnp.where(hit, posc, C)].set(True, mode="drop")
+        n_marked = s.n_marked + jnp.sum(hit).astype(jnp.int32)
+        s2 = s._replace(term_mark=mark, n_marked=n_marked)
+        inv = jnp.zeros((K,), jnp.int32).at[order].set(jnp.arange(K, dtype=jnp.int32))
+        deleted = hit[inv]
     s2 = jax.lax.cond(n_marked * compact_den > s2.n_term * compact_num,
                       compact, lambda t: t, s2)
-
-    inv = jnp.zeros((K,), jnp.int32).at[order].set(jnp.arange(K, dtype=jnp.int32))
-    return s2, hit[inv]
+    return s2, deleted
 
 
+@jax.named_scope("obs.reclaim")
 def compact(s: DetSkiplist) -> DetSkiplist:
     """Physically remove tombstones and rebuild all levels (the wholesale
     merge/borrow + DecreaseDepth: stale index nodes vanish here)."""
@@ -423,6 +431,7 @@ def pop_mark(s: DetSkiplist, idx: jnp.ndarray, hit: jnp.ndarray,
 # Range deletion (bulk DropKey over [lo, hi) intervals)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("obs.range_delete")
 def range_delete_batch(s: DetSkiplist, lo: jnp.ndarray, hi: jnp.ndarray,
                        mask: jnp.ndarray | None = None, compact_num: int = 1,
                        compact_den: int = 4):

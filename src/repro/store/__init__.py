@@ -31,9 +31,10 @@ engine    the mesh-sharded engine (hierarchical all_to_all routing + local
 obs       the observability layer: `obs:`-prefixed backends carry a
           deterministic jit-carried metrics plane (`METRICS_SCHEMA`,
           bit-identical across exec modes and shardings, like results),
-          and `span`/`tracing` record host trace spans exportable as
-          Chrome-trace/Perfetto JSON (tools/trace_export.py,
-          docs/observability.md)
+          `scope` names device phases for `jax.profiler` traces, and
+          `span`/`tracing` record host spans on the profiler's clock and
+          into a Tracer exportable as Chrome-trace/Perfetto JSON
+          (tools/trace_export.py, docs/observability.md)
 
 The stack is three explicit layers: `core.layout` owns the flat-memory
 shapes, `store.exec` owns probe execution over them, and this package's
@@ -48,8 +49,8 @@ from repro.store.api import (OP_DELETE, OP_FIND, OP_INSERT, OP_NONE, OP_POPK,
                              available_backends, get_backend, make_plan,
                              register, uniform_stats)
 from repro.store.obs import (METRICS_SCHEMA, SERVING_SCHEMA, SPAN_TAXONOMY,
-                             ObservedStore, Tracer, current_tracer, span,
-                             tracing, uniform_serving_metrics)
+                             ObservedStore, Tracer, current_tracer, scope,
+                             span, tracing, uniform_serving_metrics)
 
 __all__ = [
     "OP_DELETE", "OP_FIND", "OP_INSERT", "OP_NONE", "OP_POPK", "OP_POPMIN",
@@ -57,6 +58,6 @@ __all__ = [
     "STATS_SCHEMA", "OpPlan", "OpResults", "Store", "available_backends",
     "get_backend", "make_plan", "register", "uniform_stats",
     "METRICS_SCHEMA", "SERVING_SCHEMA", "SPAN_TAXONOMY", "ObservedStore",
-    "Tracer", "current_tracer", "span", "tracing",
+    "Tracer", "current_tracer", "scope", "span", "tracing",
     "uniform_serving_metrics",
 ]

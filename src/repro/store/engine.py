@@ -95,7 +95,7 @@ def make_store_step(mesh: Mesh, axis_names: Sequence[str], lanes: int,
         sl = jax.tree.map(lambda x: x[0], state)   # this shard's instance
         valid = ops >= 0
         with (obs.collect() if observed else nullcontext(None)) as frame:
-            with obs.span("route", backend=be.name):
+            with obs.scope("route"):
                 rr = route_to_owners(keys, vals, ops, valid, axis_names,
                                      axis_sizes, pool)
             if observed:
@@ -109,9 +109,10 @@ def make_store_step(mesh: Mesh, axis_names: Sequence[str], lanes: int,
         with exec_.exec_mode(mode):   # baked in at trace time
             sl, res = be.apply(sl, plan)
         sl = obs.absorb_frame(sl, frame)
-        resv, okb = route_back(res.vals, res.ok, rr.origin,
-                               rr.valid & (rr.aux >= 0), axis_names,
-                               axis_sizes, lanes)
+        with obs.scope("route"):
+            resv, okb = route_back(res.vals, res.ok, rr.origin,
+                                   rr.valid & (rr.aux >= 0), axis_names,
+                                   axis_sizes, lanes)
         state2 = jax.tree.map(lambda a, b: b[None], state, sl)
         return state2, resv, okb, rr.dropped[None]   # [1]/shard -> [S] global
 
@@ -221,10 +222,18 @@ class StoreEngine:
         self.pool_factor = pool_factor
         self.n_shards = int(math.prod(mesh.shape[a] for a in self.axis_names))
         self.sharding = store_sharding(mesh, self.axis_names)
-        self._jit_step = jax.jit(make_store_step(mesh, self.axis_names, lanes,
-                                                 backend=self.backend,
-                                                 pool_factor=pool_factor,
-                                                 exec_mode=exec_mode))
+        step = make_store_step(mesh, self.axis_names, lanes,
+                               backend=self.backend, pool_factor=pool_factor,
+                               exec_mode=exec_mode)
+
+        def counted(*args):
+            # runs only while jax traces the step (a new shape or dtype):
+            # a recompile shows in `traces`, at no cost per call
+            self.traces += 1
+            return step(*args)
+
+        self.traces = 0
+        self._jit_step = jax.jit(counted)
         # host-side step sequence number: incremented once per `step()` call,
         # surfaced in `stats()` and the "step" span. The resilience journal
         # keys its entries off this counter (`journal.py` restores it on
@@ -234,15 +243,17 @@ class StoreEngine:
         self.seq = 0
 
     def step(self, state, ops, keys, vals):
-        """One batched-op step, wrapped in the `"step"` trace span (real
-        per-batch wall time when a `obs.tracing()` block is active — the
-        timeline row `tools/trace_export.py` exports). Each call advances
-        the host-side `seq` counter; the span carries the seq of the step
-        it timed."""
+        """One batched-op step inside the `"step"` host span: a
+        `jax.profiler.StepTraceAnnotation("obs.step", step_num=seq)` on the
+        profiler's clock, and a Tracer span when a `obs.tracing()` block is
+        active. The span times the dispatch (the step returns before the
+        device finishes) and carries `traces`, the engine's trace count
+        before the call. Each call advances the host-side `seq` counter."""
         seq = self.seq
         self.seq += 1
-        with obs.span("step", backend=self.backend.name, lanes=self.lanes,
-                      shards=self.n_shards, seq=seq):
+        with obs.span("step", step_num=seq, backend=self.backend.name,
+                      lanes=self.lanes, shards=self.n_shards,
+                      traces=self.traces):
             return self._jit_step(state, ops, keys, vals)
 
     def init(self, capacity_per_shard: int, **kw):
@@ -257,10 +268,13 @@ class StoreEngine:
     def stats(self, state) -> dict:
         """Per-shard `STATS_SCHEMA` arrays plus the engine-level `"seq"`
         (host step counter — how many steps this engine has applied; the
-        journal's next entry number). `"seq"` is engine metadata, not part
-        of `api.STATS_SCHEMA`: backend stats stay schema-exact."""
+        journal's next entry number) and `"traces"` (how many times the
+        step was traced: 1 once warm, more after a recompile). Both are
+        engine metadata, not part of `api.STATS_SCHEMA`: backend stats
+        stay schema-exact."""
         out = sharded_stats(self.backend, state)
         out["seq"] = self.seq
+        out["traces"] = self.traces
         return out
 
     def metrics(self, state) -> dict:

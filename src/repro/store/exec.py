@@ -54,9 +54,9 @@ TRACED, which is exactly once per launch in the compiled step — the unit
 the fused tier find/apply kernels exist to minimize. Benchmarks and the
 fused-path tests read it to report dispatches per plan, probe and update
 halves separately. Meters are CONTEXT-LOCAL and NESTABLE (see
-`measure_dispatches`); each dispatch also opens an `obs.span("find", ...)`
-or `obs.span("update", ...)` so trace exports attribute lowering cost per
-entry.
+`measure_dispatches`); each dispatch also opens the device phase
+`obs.scope("find")` or `obs.scope("update")`, so a `jax.profiler` trace
+attributes the entry's device ops to its phase.
 """
 from __future__ import annotations
 
@@ -273,23 +273,21 @@ def measure_dispatches():
 
 
 def _probe(fn):
-    """Shared probe-entry decorator: one dispatch tick + one
-    `obs.span("find", probe=<name>)` per entry (the span records lowering
-    wall time when a tracer is installed and names the scope for
-    `jax.profiler` either way)."""
+    """Shared probe-entry decorator: one dispatch tick + the
+    `obs.scope("find")` device phase per entry."""
     from repro.store import obs
 
     @functools.wraps(fn)
     def wrapped(*args, **kw):
         _bump("probe")
-        with obs.span("find", cat="dispatch", probe=fn.__name__):
+        with obs.scope("find"):
             return fn(*args, **kw)
     return wrapped
 
 
 def _update(fn):
-    """Write-path twin of `_probe`: one "update"-kind dispatch tick + one
-    `obs.span("update", probe=<name>)` per entry. Update dispatches are the
+    """Write-path twin of `_probe`: one "update"-kind dispatch tick + the
+    `obs.scope("update")` device phase per entry. Update dispatches are the
     half of an apply the fused tier-apply kernel collapses; the split
     counters are what the fused-vs-unfused bench rows report."""
     from repro.store import obs
@@ -297,7 +295,7 @@ def _update(fn):
     @functools.wraps(fn)
     def wrapped(*args, **kw):
         _bump("update")
-        with obs.span("update", cat="dispatch", probe=fn.__name__):
+        with obs.scope("update"):
             return fn(*args, **kw)
     return wrapped
 

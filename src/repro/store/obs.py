@@ -22,23 +22,29 @@ and whose `metrics(state)` accessor returns the counter dict. Instrumented
 modules (`store/backends.py`, `store/tiers.py`, `store/engine.py`) call
 `record(name, thunk)` at the accumulation points; with NO collection frame
 active (i.e. every un-wrapped backend) `record` returns before evaluating
-the thunk, so observability off costs nothing — the acceptance bar is < 5%
-apply wall time vs the uninstrumented baseline (`BENCH_tiers.json` carries
-an ``/obs`` row documenting the enabled cost too).
+the thunk, so an un-observed store traces no counter ops at all.
 
-**Plane 2 — host trace spans.** `span(name, **args)` records wall-clock
-spans into a context-local `Tracer` (installed with `tracing()`), and
-always enters `jax.named_scope` so the same names annotate the lowered
-HLO/Pallas kernels for `jax.profiler` timelines. The span taxonomy
-(`SPAN_TAXONOMY`) covers the engine step ("route"/"step"), the exec
-dispatch layer ("find" for probe dispatches, "update" for state-writing
-dispatches, with the entry name as an arg), the tier stack's
-apply phases ("insert"/"delete"/"demote"/"promote"/"compact"/"flush"), and
-the serving engine's host loop ("admit"/"prefill"/"decode"). Spans around
-TRACED code measure trace/lowering time (they fire once per compilation);
-spans around host loops (engine step, serving admit/decode) measure real
-per-batch wall time. `tools/trace_export.py` exports a Tracer as
-Chrome-trace JSON that loads in Perfetto (see docs/observability.md).
+**Plane 2 — phase names, in two roles** (`SPAN_TAXONOMY` =
+`DEVICE_PHASES` + `HOST_PHASES`; docs/observability.md lists what each
+wraps and which benchmark metric reads it):
+
+* Device phases — `scope(name)` — open `jax.named_scope("obs.<name>")`
+  and nothing else. The name lands in the `op_name` of every HLO op
+  traced inside, so a `jax.profiler` trace attributes device time to the
+  phase; it costs nothing at run time and records nothing on the host.
+  They cover the engine's routing ("route"), the exec dispatch layer
+  ("find" / "update"), the tier stack's phases, and the skiplist's write
+  passes ("merge", "rebuild", "tombstone", "range_delete", "reclaim";
+  `core/det_skiplist.py` opens those scopes itself, since `core` does
+  not import `repro.store`).
+* Host phases — `span(name, **args)` — open a
+  `jax.profiler.TraceAnnotation("obs.<name>")` (a
+  `StepTraceAnnotation` when given `step_num`), so they sit on the
+  profiler's clock beside the device ops, and record wall time + args
+  into a context-local `Tracer` when one is installed (`tracing()`).
+  They wrap host loops only: the engine step, eager scans, the serving
+  loop, recoveries. `tools/trace_export.py` exports a Tracer as
+  Chrome-trace JSON that loads in Perfetto.
 
 The serving engine's host-side counters (`SERVING_SCHEMA`) share the same
 closed-schema discipline via `uniform_serving_metrics`, so the serving
@@ -120,16 +126,20 @@ SERVING_SCHEMA = ("ring_depth", "prefix_hits", "prefix_lookups",
                   "prefix_hit_rate", "batch_fill", "decode_steps",
                   "decode_tokens")
 
-# span names (docs/observability.md lists what each phase wraps); `span`
-# accepts any name, but the instrumented modules stick to this taxonomy so
-# traces from different runs line up in Perfetto
-SPAN_TAXONOMY = ("route", "step", "find", "update", "insert", "delete",
-                 "pop", "demote", "promote", "compact", "flush", "scan",
-                 "admit", "prefill", "decode",
-                 # fault tolerance (store/resilience/restore.py): wraps a
-                 # quarantined shard's snapshot+journal rebuild — args carry
-                 # the shard id, journal replay length, and recovery mode
-                 "recover")
+# phase names (docs/observability.md lists what each phase wraps). Device
+# phases are named scopes on traced code (`scope`); host phases are
+# profiler annotations + Tracer spans around host work (`span`). "scan" is
+# both: a host span when called eagerly, a scope when traced.
+DEVICE_PHASES = ("route", "find", "update", "insert", "delete", "pop",
+                 "demote", "promote", "compact", "flush",
+                 # the deterministic skiplist's passes (core/det_skiplist.py)
+                 "merge", "rebuild", "tombstone", "range_delete", "reclaim")
+HOST_PHASES = ("step", "scan", "admit", "prefill", "decode",
+               # fault tolerance (store/resilience/restore.py): wraps a
+               # quarantined shard's snapshot+journal rebuild — args carry
+               # the shard id, journal replay length, and recovery mode
+               "recover")
+SPAN_TAXONOMY = DEVICE_PHASES + HOST_PHASES
 
 # bytes one routed op carries through the engine's all_to_all queues:
 # key u64 + val u64 + op i32 + origin i32 (core/routing.py lane payload)
@@ -273,8 +283,12 @@ class ObservedStore:
 
     def scan(self, state: ObservedState, lo, hi, max_out: int, **kw):
         # **kw forwards backend-specific scan options (e.g. the ordered
-        # skiplist backends' snapshot `as_of_batch=`) untouched
-        with span("scan", backend=self.inner.name):
+        # skiplist backends' snapshot `as_of_batch=`) untouched. Inside a
+        # trace (a range step) "scan" names the device ops; called eagerly
+        # it is a host span.
+        traced = isinstance(lo, jax.core.Tracer)
+        with (scope("scan") if traced
+              else span("scan", backend=self.inner.name)):
             return self.inner.scan(state.inner, lo, hi, max_out, **kw)
 
     def stats(self, state: ObservedState):
@@ -331,7 +345,6 @@ def absorb_frame(state, frame) -> Any:
 
 class Span(NamedTuple):
     name: str
-    cat: str
     ts_ns: int          # absolute perf_counter_ns at entry
     dur_ns: int
     args: Dict[str, Any]
@@ -346,10 +359,10 @@ class Tracer:
         self.t0_ns = time.perf_counter_ns()
         self.spans: list[Span] = []
 
-    def add(self, name: str, cat: str, ts_ns: int, dur_ns: int,
+    def add(self, name: str, ts_ns: int, dur_ns: int,
             args: Dict[str, Any]) -> None:
-        self.spans.append(Span(name=name, cat=cat, ts_ns=ts_ns,
-                               dur_ns=dur_ns, args=args))
+        self.spans.append(Span(name=name, ts_ns=ts_ns, dur_ns=dur_ns,
+                               args=args))
 
 
 _TRACER: ContextVar[Tracer | None] = ContextVar("repro_obs_tracer",
@@ -372,18 +385,39 @@ def current_tracer() -> Tracer | None:
     return _TRACER.get()
 
 
+def scope(name: str):
+    """A device phase: `jax.named_scope("obs.<name>")`, which puts the name
+    on the `op_name` of every op traced inside (how a `jax.profiler` trace
+    attributes device time). Nothing is recorded on the host: around
+    traced code a wall clock would time lowering, once per compile.
+    `name` is one of `DEVICE_PHASES`, or "scan" (traced in a range step)."""
+    if name not in DEVICE_PHASES + ("scan",):
+        raise ValueError(f"{name!r} is not a device phase; extend "
+                         f"obs.DEVICE_PHASES (and its docs table)")
+    return jax.named_scope(f"obs.{name}")
+
+
 @contextmanager
-def span(name: str, cat: str = "host", **args):
-    """One trace span. Always enters `jax.named_scope("obs.<name>")` so the
-    phase name reaches the lowered HLO / Pallas kernels (visible in
-    `jax.profiler` timelines); when a Tracer is installed (`tracing()`),
-    also records wall time + args for the Chrome-trace export. Without a
-    tracer the cost is one contextvar read."""
+def span(name: str, *, step_num: int | None = None, **args):
+    """A host phase: a `jax.profiler.TraceAnnotation("obs.<name>", **args)`
+    — a `StepTraceAnnotation` numbered `step_num` when one is given — so
+    the span sits on the profiler's clock beside the device ops; when a
+    Tracer is installed (`tracing()`), also records wall time + args for
+    the Chrome-trace export. Wrap host work only (never traced code): the
+    annotation is a no-op unless a profiler trace is running, and without
+    a tracer the Tracer side costs one contextvar read."""
     tr = _TRACER.get()
     t0 = time.perf_counter_ns() if tr is not None else 0
-    with jax.named_scope(f"obs.{name}"):
+    if step_num is None:
+        ann = jax.profiler.TraceAnnotation(f"obs.{name}", **args)
+    else:
+        ann = jax.profiler.StepTraceAnnotation(f"obs.{name}",
+                                               step_num=step_num, **args)
+    with ann:
         try:
             yield
         finally:
             if tr is not None:
-                tr.add(name, cat, t0, time.perf_counter_ns() - t0, args)
+                if step_num is not None:
+                    args = {**args, "step_num": step_num}
+                tr.add(name, t0, time.perf_counter_ns() - t0, args)

@@ -73,7 +73,7 @@ class PQSkiplistBackend:
             # INSERTS -> DELETES -> RANGE_DELETES -> POPS -> FINDS with
             # the insert/delete/range-delete half shared with det_skiplist
             ranks = jnp.cumsum(pop_m.astype(jnp.int32)) - 1
-            with obs.span("pop", backend=self.name):
+            with obs.scope("pop"):
                 popped, pkeys, pidx = exec_.pq_pop(heap, ranks, pop_m)
                 pvals = jnp.where(popped, heap.term_vals[pidx], jnp.uint64(0))
                 heap = dsl.pop_mark(heap, pidx, popped)

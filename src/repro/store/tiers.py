@@ -310,7 +310,7 @@ class TieredBackend:
         """Push lanes down: warm skiplist first; lanes the skiplist cannot
         take (capacity) append to the spill runs (depth 3) or drop (depth 2
         — the flat backend's allocation-failure analogue)."""
-        with obs.span("demote", backend=self.name):
+        with obs.scope("demote"):
             cold, ok_c, ex_c = dsl.insert_batch(cold, keys, vals, mask)
             ok = ok_c | ex_c
             if spill is not None:
@@ -371,7 +371,7 @@ class TieredBackend:
         # Fused: membership + the whole hot-insert prologue (bucket plan,
         # victim selection) is ONE tier_apply dispatch per plan; unfused:
         # one probe dispatch per lower tier, then one hot_update dispatch.
-        with obs.span("insert", backend=self.name):
+        with obs.scope("insert"):
             ins_k = jnp.where(ins_m, keys, KEY_INF)
             self._record_probe_cost(cold, spill, ins_k)
             if self.fused:
@@ -408,7 +408,7 @@ class TieredBackend:
             existed = ex_hot | in_cold | in_spill
 
         # DELETES: the single-tier invariant means exactly one tier can hit
-        with obs.span("delete", backend=self.name):
+        with obs.scope("delete"):
             hot, del_hot = ht.fixed_delete(hot, keys, del_m)
             cold, del_cold = dsl.delete_batch(cold, keys, del_m & ~del_hot)
             if spill is not None:
@@ -423,7 +423,7 @@ class TieredBackend:
         # plan (dispatch count independent of tier depth); unfused: one
         # dispatch per tier. Either way the hot probe reports the hit
         # column so the LRU policy can refresh its stamps.
-        with obs.span("find", backend=self.name):
+        with obs.scope("find"):
             self._record_probe_cost(cold, spill, qk)
             if self.fused:
                 ((f_hot, v_hot, c_hot), (f_cold, v_cold),
@@ -465,7 +465,7 @@ class TieredBackend:
         # PROMOTION (after the linearization point; membership-neutral):
         # warm/spill-served FIND lanes migrate up, displacing policy victims
         if self.promote:
-            with obs.span("promote", backend=self.name):
+            with obs.scope("promote"):
                 prom = valid & (ops == OP_FIND) & found & ~f_hot
                 pv = jnp.where(f_cold, v_cold, v_spill)
                 if self.policy == "none":
@@ -493,7 +493,7 @@ class TieredBackend:
         # and keep the live run count under the static MAX_SPILL_RUNS cap
         # the per-run probe's boundary plane is sized for
         if spill is not None:
-            with obs.span("compact", backend=self.name):
+            with obs.scope("compact"):
                 pre_dead = spill.n_dead
                 spill = spill_maintain(spill)
                 obs.record("tombstones_reclaimed",
@@ -556,14 +556,14 @@ class TieredBackend:
         cumulative eviction / promotion counters are PRESERVED — flushing
         the tier must not erase the policy's history (the
         hot-tier-exactly-full audit)."""
-        with obs.span("flush", backend=self.name):
+        with obs.scope("flush"):
             shape = state.hot.keys.shape
             hk = state.hot.keys.reshape(-1)
             hv = state.hot.vals.reshape(-1)
             cold, spill, ok = self._demote(state.cold, state.spill, hk, hv,
                                            hk != EMPTY)
             if spill is not None:   # keep the run count under the static cap
-                with obs.span("compact", backend=self.name):
+                with obs.scope("compact"):
                     pre_dead = spill.n_dead
                     spill = spill_maintain(spill)
                     obs.record("tombstones_reclaimed",

@@ -1,13 +1,15 @@
 """Multi-device validation program for the sharded store engine.
 
-Run under XLA_FLAGS=--xla_force_host_platform_device_count=8 by
-tests/test_routing_store.py. Builds a (2, 4) ("pod", "data") mesh — a
-miniature of the production (2, 16, 16) — and, for EVERY backend listed in
-BACKENDS (flat skiplist, hash tables, split-order, and the tiered
-hash+skiplist stack), applies random batched ops through the hierarchical
-router and checks every result against a global dict model. The uniform
+Run under XLA_FLAGS=--xla_force_host_platform_device_count=8 by the
+`run_store_prog` fixture of tests/conftest.py. Builds a (2, 4)
+("pod", "data") mesh — a miniature of the production (2, 16, 16) — and,
+for EVERY backend listed in BACKENDS (flat skiplist, hash tables,
+split-order, and the tiered hash+skiplist stack), applies random batched
+ops through the hierarchical router and checks every result against a
+global dict model. The uniform
 `repro.store` protocol is what lets one program validate all of them.
-Exits 0 on success.
+`python store_prog.py [group ...]` runs the named groups of `GROUPS`
+(every group when none is named). Exits 0 on success.
 """
 import os
 import sys
@@ -205,13 +207,16 @@ def check_tier_residency(mesh, backend: str = "tiered3/lru") -> None:
             assert int(dropped) == 0, mode
 
         be = get_backend(backend)
+        # every shard's sub-plan has LANES lanes: one compile per mode
+        # (a fresh function, so the mode is read when it traces)
+        step = jax.jit(lambda st, plan: be.apply(st, plan))
         for s in range(N_SHARDS):
             with exec_.exec_mode(mode):
                 direct = be.init(64, **init_kw)
                 for ops, keys in rounds:
                     owner = (keys >> np.uint64(61)).astype(np.int32)
                     sel = owner == s
-                    direct, _ = be.apply(direct, make_plan(
+                    direct, _ = step(direct, make_plan(
                         ops[sel], keys[sel], keys[sel] + 3))
             sharded = jax.tree.map(lambda x, s=s: x[s], state)
             la, lb = jax.tree.leaves(sharded), jax.tree.leaves(direct)
@@ -446,6 +451,9 @@ def check_metrics(mesh, backend: str = "obs:tiered3/lru") -> None:
         assert set(per_shard) == set(obs.METRICS_SCHEMA)
 
         be = get_backend(backend)
+        # sub-plans padded to the pool: one compile per mode (a fresh
+        # function, so the mode is read when it traces)
+        step = jax.jit(lambda st, plan: be.apply(st, plan))
         for s in range(N_SHARDS):
             with exec_.exec_mode(mode):
                 direct = be.init(64, **init_kw)
@@ -461,7 +469,7 @@ def check_metrics(mesh, backend: str = "obs:tiered3/lru") -> None:
                     p_keys = np.zeros(pool, np.uint64)
                     p_ops[:n] = ops[sel]
                     p_keys[:n] = keys[sel]
-                    direct, _ = be.apply(direct, make_plan(
+                    direct, _ = step(direct, make_plan(
                         p_ops, p_keys, p_keys + 3,
                         mask=np.arange(pool) < n))
             m_dir = {k: int(v) for k, v in be.metrics(direct).items()}
@@ -691,22 +699,31 @@ def check_recover(mesh, backend: str = "obs:tiered3/lru") -> None:
           f"sync_digest=match degraded_completions={n_def}")
 
 
-def main() -> int:
-    mesh = make_mesh((2, 4), AXES)
+def check_store(mesh) -> None:
     for backend in BACKENDS:
         check_backend(mesh, backend)
     for backend in ("det_skiplist", "hash+skiplist"):
         check_range(mesh, backend)
-    check_uneven_occupancy(mesh)
-    check_tier_residency(mesh)
-    check_fused_vs_unfused(mesh)
-    check_fused_apply(mesh)
-    check_bskip(mesh)
-    check_metrics(mesh)
-    check_pq(mesh)
-    check_recover(mesh)
+
+
+# groups of checks of a few minutes each: the tests launch each group as
+# its own process, so that pytest-xdist runs them on separate workers
+GROUPS = {
+    "store": (check_store, check_uneven_occupancy, check_pq),
+    "residency": (check_tier_residency, check_bskip, check_fused_vs_unfused,
+                  check_fused_apply),
+    "metrics": (check_metrics, check_recover),
+}
+
+
+def main(groups) -> int:
+    """Run the named groups of checks, every group when none is named."""
+    mesh = make_mesh((2, 4), AXES)
+    for group in groups or GROUPS:
+        for check in GROUPS[group]:
+            check(mesh)
     return 0
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    sys.exit(main(sys.argv[1:]))

@@ -43,9 +43,10 @@ def _run_apply(name, mode, plans, capacity=2048, **init_kw):
     be = get_backend(name)
     with exec_.exec_mode(mode):
         st = be.init(capacity, **init_kw)
+        step = jax.jit(be.apply)      # traced in `mode`
         outs = []
         for p in plans:
-            st, res = be.apply(st, p)
+            st, res = step(st, p)
             outs.append((np.asarray(res.ok), np.asarray(res.vals)))
         stats = {k: int(v) for k, v in be.stats(st).items()}
     return st, outs, stats
@@ -143,6 +144,18 @@ def test_mode_plumbing():
     with pytest.raises(ValueError):
         with exec_.exec_mode("nope"):
             pass
+
+
+@pytest.mark.slow
+def test_sharded_tier_residency_multidevice(run_store_prog):
+    """The tier stack's residency across modes, the blocked skiplist and
+    the fused probe and apply paths, on the 8-device mesh: store_prog.py's
+    "residency" group."""
+    out = run_store_prog("residency")
+    assert "RESIDENCY-OK" in out
+    assert "BSKIP-OK" in out
+    assert "FUSED-OK" in out
+    assert "APPLY-OK" in out
 
 
 def test_engine_exec_mode_single_device():

@@ -6,7 +6,8 @@ way results are — bit-identical across every runnable exec mode
 and across shardings (the 1-device engine here; 8 shards in
 tests/multidev/store_prog.py's METRICS-OK lane). Plus: the counters are
 CORRECT on hand-built plans, the plane jits and carries across steps, the
-exec dispatch meters are context-local and nestable, spans export as
+exec dispatch meters are context-local and nestable, device phases name
+the lowered step, host spans reach the profiler's trace and export as
 Chrome-trace JSON, and `tools/bench_diff.py --assert-within` gates
 regressions with the right exit codes.
 """
@@ -175,7 +176,7 @@ class TestMetricsParity:
               or name == "obs:hash+skiplist" else {})
         ref = None
         for mode in exec_.runnable_modes():
-            st = apply_stream(be, plans, mode, **kw)
+            st = apply_stream(be, plans, mode, jit=True, **kw)
             m = as_ints(be.metrics(st))
             if ref is None:
                 ref = m
@@ -189,8 +190,10 @@ class TestMetricsParity:
         kw = dict(hot_bucket=4, hot_frac=8)
         fused = get_backend(f"obs:{name}")
         unf = obs.ObservedStore(unfused_twin(name))
-        mf = as_ints(fused.metrics(apply_stream(fused, plans, "jnp", **kw)))
-        mu = as_ints(unf.metrics(apply_stream(unf, plans, "jnp", **kw)))
+        mf = as_ints(fused.metrics(apply_stream(fused, plans, "jnp",
+                                                jit=True, **kw)))
+        mu = as_ints(unf.metrics(apply_stream(unf, plans, "jnp", jit=True,
+                                              **kw)))
         assert mf == mu
 
     def test_engine_matches_direct_replay(self):
@@ -240,6 +243,14 @@ class TestMetricsParity:
             assert m_eng[k] == m_dir[k], k
         assert m_eng["routed_ops"] == total_valid
         assert m_eng["routed_bytes"] == obs.ROUTED_OP_BYTES * total_valid
+
+    @pytest.mark.slow
+    def test_engine_metrics_multidevice(self, run_store_prog):
+        # METRICS-OK, 8-shard form, and RECOVER-OK (a restored or
+        # recovered shard's metrics plane): store_prog.py's "metrics" group
+        out = run_store_prog("metrics")
+        assert "METRICS-OK" in out
+        assert "RECOVER-OK" in out
 
     def test_plain_backend_state_unchanged(self):
         # wrapping is opt-in: the un-prefixed backend's state pytree carries
@@ -301,19 +312,45 @@ class TestDispatchMeters:
 # spans + chrome-trace export
 # ---------------------------------------------------------------------------
 
+def scope_names(lowered) -> set:
+    """The `obs.*` phase names on the debug locations of a lowered step."""
+    import re
+    text = lowered.as_text(debug_info=True)
+    return {part[len("obs."):]
+            for loc in re.findall(r'loc\("([^"]*)"(?!:)', text)
+            for part in loc.split("/") if part.startswith("obs.")}
+
+
+def one_shard_engine(backend, lanes=16):
+    from repro.launch.mesh import make_mesh
+    from repro.store.engine import StoreEngine
+    mesh = make_mesh((1,), ("d",), devices=jax.devices()[:1])
+    # jnp: the Pallas modes add their kernels' own `obs.kernel.*` names
+    return StoreEngine(mesh, ("d",), lanes=lanes, backend=backend,
+                       exec_mode="jnp")
+
+
+def engine_args(eng, capacity=64, seed=0):
+    rng = np.random.default_rng(seed)
+    state = jax.device_put(eng.init(capacity), eng.sharding)
+    ops = jnp.asarray(rng.integers(0, 3, eng.lanes).astype(np.int32))
+    keys = jnp.asarray(rng.integers(1, 48, eng.lanes).astype(np.uint64))
+    return state, ops, keys, keys
+
+
 class TestSpans:
     def test_span_noop_without_tracer(self):
         assert obs.current_tracer() is None
-        with obs.span("find"):       # must not raise or record anywhere
+        with obs.span("scan"):       # must not raise or record anywhere
             pass
 
     def test_tracer_records_nested_spans(self):
         with obs.tracing() as tr:
             with obs.span("step", backend="x"):
-                with obs.span("find", cat="dispatch"):
+                with obs.span("admit"):
                     pass
         names = [s.name for s in tr.spans]
-        assert names == ["find", "step"]      # inner closes first
+        assert names == ["admit", "step"]      # inner closes first
         step = tr.spans[1]
         assert step.args == {"backend": "x"}
         assert step.dur_ns >= tr.spans[0].dur_ns
@@ -333,16 +370,83 @@ class TestSpans:
         json.dumps(payload)      # must be JSON-serializable as-is
 
     def test_apply_emits_taxonomy_spans(self):
+        # the tier stack's phases and the warm skiplist's passes name the
+        # lowered HLO; lowering under a Tracer records nothing in it
         be = get_backend("obs:tiered3/lru")
         st = be.init(64, hot_bucket=4, hot_frac=8)
-        with obs.tracing() as tr:
-            st, _ = be.apply(st, make_plan([OP_INSERT, OP_FIND], [1, 1],
-                                           [7, 0]))
-        names = {s.name for s in tr.spans}
-        assert {"insert", "delete", "find", "promote",
-                "compact"} <= names
-        assert names <= set(obs.SPAN_TAXONOMY) | {"demote"}
-        assert all(s.name in obs.SPAN_TAXONOMY for s in tr.spans)
+        plan = make_plan([OP_INSERT, OP_FIND], [1, 1], [7, 0])
+        with obs.tracing() as tr, exec_.exec_mode("jnp"):
+            names = scope_names(jax.jit(be.apply).lower(st, plan))
+        assert {"insert", "delete", "find", "promote", "demote", "compact",
+                "merge", "rebuild", "tombstone", "reclaim"} <= names
+        assert names <= set(obs.SPAN_TAXONOMY)
+        assert tr.spans == []
+
+    @pytest.mark.parametrize("backend, present", [
+        ("det_skiplist", {"route", "find", "merge", "rebuild", "tombstone",
+                          "range_delete", "reclaim"}),
+        ("obs:tiered3/lru", {"route", "find", "update", "merge", "rebuild",
+                             "tombstone", "reclaim", "demote"}),
+    ])
+    def test_engine_step_scopes_in_taxonomy(self, backend, present):
+        eng = one_shard_engine(backend)
+        names = scope_names(eng._jit_step.lower(*engine_args(eng)))
+        assert present <= names
+        assert names <= set(obs.DEVICE_PHASES)
+
+    def test_scope_rejects_unknown_phase(self):
+        with pytest.raises(ValueError):
+            obs.scope("not_a_phase")
+        with pytest.raises(ValueError):      # a host phase is not a scope
+            obs.scope("step")
+        obs.scope("scan")                    # "scan" plays both roles
+
+    def test_profiler_trace_numbers_engine_steps(self, tmp_path):
+        # each StoreEngine.step is an `obs.step` host event on the
+        # profiler's clock, numbered by the engine's seq
+        from jax.profiler import ProfileData
+        eng = one_shard_engine("det_skiplist")
+        state, ops, keys, vals = engine_args(eng)
+        state, *_ = eng.step(state, ops, keys, vals)        # compile first
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            for _ in range(3):
+                state, *_ = eng.step(state, ops, keys, vals)
+            jax.block_until_ready(state)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = tmp_path.glob("**/*.xplane.pb")
+        steps = sorted((e.start_ns, dict(e.stats))
+                       for p in ProfileData.from_file(str(path)).planes
+                       for line in p.lines for e in line.events
+                       if e.name == "obs.step")
+        assert [a["step_num"] for _, a in steps] == [1, 2, 3]
+        assert all(a["traces"] == 1 and a["backend"] == "det_skiplist"
+                   for _, a in steps)
+
+    def test_retrace_counter(self):
+        eng = one_shard_engine("det_skiplist")
+        args = engine_args(eng)
+        state, *_ = eng.step(*args)
+        state, *_ = eng.step(state, *args[1:])
+        assert eng.stats(state)["traces"] == 1     # same shapes: no retrace
+        bigger = jax.device_put(eng.init(128), eng.sharding)
+        state, *_ = eng.step(bigger, *args[1:])
+        assert eng.stats(state)["traces"] == 2
+        assert eng.stats(state)["seq"] == 3
+
+    def test_demo_export_holds_host_spans_only(self):
+        sys.path.insert(0, os.path.join(ROOT, "tools"))
+        import trace_export
+        tracer, _ = trace_export.record_demo_trace("obs:det_skiplist",
+                                                   steps=2, lanes=16)
+        assert [s.name for s in tracer.spans] == ["step", "step"]
+        assert [s.args["step_num"] for s in tracer.spans] == [0, 1]
+        payload = trace_export.to_chrome_trace(tracer)
+        assert {e["name"] for e in payload["traceEvents"]
+                if e["ph"] == "X"} == {"step"}
 
 
 # ---------------------------------------------------------------------------

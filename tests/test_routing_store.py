@@ -1,27 +1,18 @@
-"""Launches the 8-device sharded-store validation as a subprocess (device
-count must be fixed before JAX initializes, so it cannot share this process).
+"""Runs the "store" group of the 8-device sharded-store validation
+(`tests/multidev/store_prog.py`, through conftest's `run_store_prog`);
+`test_exec_modes.py` runs "residency" (with the fused paths) and
+`test_obs.py` "metrics" (with recovery), beside the checks of the same
+contracts on one device. pytest-xdist's loadfile queue hands out files
+with the most tests first, so this one-test file starts last: it holds
+the shortest group.
 """
-import os
-import subprocess
-import sys
-
 import pytest
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.mark.slow
-def test_sharded_store_multidevice():
-    prog = os.path.join(ROOT, "tests", "multidev", "store_prog.py")
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    out = subprocess.run([sys.executable, prog], env=env, capture_output=True,
-                         text=True, timeout=1500)
-    assert out.returncode == 0, f"stdout:\n{out.stdout}\nstderr:\n{out.stderr}"
-    assert "STORE-OK" in out.stdout
-    assert "RANGE-OK" in out.stdout
-    assert "UNEVEN-OK" in out.stdout
-    assert "RESIDENCY-OK" in out.stdout
-    assert "FUSED-OK" in out.stdout
-    assert "BSKIP-OK" in out.stdout
-    assert "PQ-OK" in out.stdout
+def test_sharded_store_multidevice(run_store_prog):
+    out = run_store_prog("store")
+    assert "STORE-OK" in out
+    assert "RANGE-OK" in out
+    assert "UNEVEN-OK" in out
+    assert "PQ-OK" in out

@@ -9,6 +9,7 @@ and the exact two-tier ordered scan.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 import repro  # noqa: F401  (enables x64)
@@ -111,9 +112,10 @@ def test_all_backends_identical_results():
     for name in ALL_BACKENDS:
         be = get_backend(name)
         st = be.init(4096)
+        step = jax.jit(be.apply)        # the plans share a shape: one compile
         out = []
         for p in plans:
-            st, res = be.apply(st, p)
+            st, res = step(st, p)
             out.append((np.asarray(res.ok), np.asarray(res.vals)))
         results[name] = out
         sizes[name] = int(be.stats(st)["size"])

@@ -347,8 +347,9 @@ def test_residency_bit_identical_across_modes(name):
     for mode in exec_.runnable_modes():
         with exec_.exec_mode(mode):
             st = be.init(64, hot_bucket=4, hot_frac=8)   # churn all tiers
+            step = jax.jit(be.apply)     # traced in `mode`
             for p in _churn_plans():
-                st, _ = be.apply(st, p)
+                st, _ = step(st, p)
         states[mode] = st
     ref_mode, ref = next(iter(states.items()))
     for mode, st in states.items():

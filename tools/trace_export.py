@@ -1,6 +1,9 @@
 """Export `repro.store.obs` trace spans as Chrome-trace / Perfetto JSON.
 
-`obs.tracing()` records host wall-clock spans (`obs.span`) into a Tracer;
+`obs.tracing()` records host wall-clock spans (`obs.span`: the engine
+step, eager scans, the serving loop, recoveries) into a Tracer; device
+phases (`obs.scope`) name HLO ops for `jax.profiler` traces and never
+reach a Tracer, so the export holds real host time only.
 `to_chrome_trace` converts one Tracer into the Chrome Trace Event format
 (JSON object with a ``traceEvents`` list of complete "X" events), which
 https://ui.perfetto.dev opens directly — see docs/observability.md for the
@@ -33,8 +36,8 @@ def to_chrome_trace(tracer, meta: dict | None = None) -> dict:
 
     Every span becomes a complete event (``ph: "X"``) with microsecond
     ``ts``/``dur`` relative to the tracer's epoch, so timestamps start near
-    zero and nested spans (engine step > route > find ...) stack in
-    Perfetto's flame view. `meta` (e.g. the final metrics plane) lands in
+    zero and nested spans (a recovery > its replayed engine steps) stack
+    in Perfetto's flame view. `meta` (e.g. the final metrics plane) lands in
     ``otherData``, the spec's free-form metadata slot."""
     events = [{
         "name": "process_name", "ph": "M", "pid": 0, "tid": 0,
@@ -43,7 +46,7 @@ def to_chrome_trace(tracer, meta: dict | None = None) -> dict:
     for s in tracer.spans:
         events.append({
             "name": s.name,
-            "cat": s.cat,
+            "cat": "host",
             "ph": "X",
             "ts": (s.ts_ns - tracer.t0_ns) / 1e3,
             "dur": s.dur_ns / 1e3,
@@ -61,10 +64,9 @@ def to_chrome_trace(tracer, meta: dict | None = None) -> dict:
 def record_demo_trace(backend: str = "obs:tiered3/lru", steps: int = 8,
                       lanes: int = 64, fault_step: int | None = None):
     """Run a small churn workload on a 1-device engine under `tracing()`;
-    returns (tracer, metrics dict of plain ints). The spans cover the whole
-    taxonomy the engine path exercises: "step" per batch (real wall time),
-    and the trace-time "route"/"insert"/"delete"/"find"/"demote"/
-    "promote"/"compact" phases from the first step's trace. With
+    returns (tracer, metrics dict of plain ints). The spans are host
+    spans: one "step" per batch (the dispatch's wall time, args carrying
+    the step number and the engine's trace count). With
     `fault_step` set, the engine is wrapped in a `ResilientEngine` with a
     shard-drop at that step, so the timeline also shows a real "recover"
     span (snapshot + journal rebuild) mid-trace."""
