@@ -17,6 +17,12 @@ Everything here runs INSIDE a shard_map body. Buckets are capacity-bounded
 (static shapes); overflow lanes are dropped and *counted* — the bounded
 analogue of the paper's unbounded queues, with the drop count surfaced so
 capacity factors can be tuned (and asserted zero in tests).
+
+Device phase: each direction's all_to_all hops (the bool<->u8 transport
+included) open `jax.named_scope("obs.exchange")`, so a `jax.profiler` trace
+tells the exchange from the bucketing and compaction around it. The name
+belongs to `repro.store.obs.DEVICE_PHASES`; this module opens the scope
+directly because `core` does not import `repro.store`.
 """
 from __future__ import annotations
 
@@ -117,9 +123,10 @@ def route_to_owners(keys: jnp.ndarray, vals: jnp.ndarray, aux: jnp.ndarray,
             digit, valid, [keys, vals, aux, origin], size, cap)
         dropped = dropped + drop
         # the queue hop: chunk i -> shard with digit i on this axis
-        k_b, v_b, a_b, o_b, val_b = (_a2a(k_b, name), _a2a(v_b, name),
-                                     _a2a(a_b, name), _a2a(o_b, name),
-                                     _a2a(val_b, name))
+        with jax.named_scope("obs.exchange"):
+            k_b, v_b, a_b, o_b, val_b = (_a2a(k_b, name), _a2a(v_b, name),
+                                         _a2a(a_b, name), _a2a(o_b, name),
+                                         _a2a(val_b, name))
         flat = lambda x: x.reshape((size * cap,) + x.shape[2:])
         keys, vals, aux, origin, valid = map(flat, (k_b, v_b, a_b, o_b, val_b))
         # re-pack to the pool budget (compact valid lanes first)
@@ -170,9 +177,10 @@ def route_back(results: jnp.ndarray, found: jnp.ndarray, origin: jnp.ndarray,
         cap = max(1, -(-pool // size))
         (r_b, f_b, s_b, l_b), val_b, _ = bucketize(
             digit, valid, [results, found.astype(jnp.int32), src, lane], size, cap)
-        r_b, f_b, s_b, l_b, val_b = (_a2a(r_b, name), _a2a(f_b, name),
-                                     _a2a(s_b, name), _a2a(l_b, name),
-                                     _a2a(val_b, name))
+        with jax.named_scope("obs.exchange"):
+            r_b, f_b, s_b, l_b, val_b = (_a2a(r_b, name), _a2a(f_b, name),
+                                         _a2a(s_b, name), _a2a(l_b, name),
+                                         _a2a(val_b, name))
         flat = lambda x: x.reshape((size * cap,) + x.shape[2:])
         results, found_i, src, lane, valid = (flat(r_b), flat(f_b), flat(s_b),
                                               flat(l_b), flat(val_b))

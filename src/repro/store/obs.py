@@ -32,11 +32,12 @@ wraps and which benchmark metric reads it):
   and nothing else. The name lands in the `op_name` of every HLO op
   traced inside, so a `jax.profiler` trace attributes device time to the
   phase; it costs nothing at run time and records nothing on the host.
-  They cover the engine's routing ("route"), the exec dispatch layer
+  They cover the engine's routing ("route", and inside it the
+  all_to_all hops, "exchange"), the exec dispatch layer
   ("find" / "update"), the tier stack's phases, and the skiplist's write
   passes ("merge", "rebuild", "tombstone", "range_delete", "reclaim";
-  `core/det_skiplist.py` opens those scopes itself, since `core` does
-  not import `repro.store`).
+  `core/det_skiplist.py` and `core/routing.py` open their scopes
+  themselves, since `core` does not import `repro.store`).
 * Host phases — `span(name, **args)` — open a
   `jax.profiler.TraceAnnotation("obs.<name>")` (a
   `StepTraceAnnotation` when given `step_num`), so they sit on the
@@ -133,7 +134,10 @@ SERVING_SCHEMA = ("ring_depth", "prefix_hits", "prefix_lookups",
 DEVICE_PHASES = ("route", "find", "update", "insert", "delete", "pop",
                  "demote", "promote", "compact", "flush",
                  # the deterministic skiplist's passes (core/det_skiplist.py)
-                 "merge", "rebuild", "tombstone", "range_delete", "reclaim")
+                 "merge", "rebuild", "tombstone", "range_delete", "reclaim",
+                 # the all_to_all hops of both routing directions, nested in
+                 # "route" (core/routing.py)
+                 "exchange")
 HOST_PHASES = ("step", "scan", "admit", "prefill", "decode",
                # fault tolerance (store/resilience/restore.py): wraps a
                # quarantined shard's snapshot+journal rebuild — args carry

@@ -46,7 +46,9 @@ def check_backend(mesh, backend: str) -> None:
     for rnd in range(ROUNDS):
         ops = rng.choice([OP_FIND, OP_INSERT, OP_DELETE], size=total,
                          p=[0.5, 0.4, 0.1]).astype(np.int32)
-        keys = rng.integers(1, 2**63, size=total, dtype=np.uint64)
+        # the full key range (all but KEY_INF and 0): every top-3-bit
+        # owner, so the "pod" hop moves keys as the "data" hop does
+        keys = rng.integers(1, 2**64 - 1, size=total, dtype=np.uint64)
         # force key reuse so finds/deletes hit
         if model:
             reuse = rng.choice(np.fromiter(model.keys(), dtype=np.uint64),
@@ -60,6 +62,8 @@ def check_backend(mesh, backend: str) -> None:
         state, res, ok, dropped = eng.step(state, ops_d, keys_d, vals_d)
         res, ok = np.asarray(res), np.asarray(ok)
         assert int(dropped) == 0, f"capacity drops: {int(dropped)}"
+        sizes = eng.stats(state)["size"]
+        assert (sizes > 0).all(), (backend, rnd, "empty shard", sizes)
 
         # model semantics: batch linearization = inserts, then deletes, then
         # finds; in-batch duplicate inserts: lowest lane wins (vals are a
@@ -93,17 +97,18 @@ def check_range(mesh, backend: str) -> None:
     state = jax.device_put(eng.init(1024), eng.sharding)
     put = lambda x: jax.device_put(jnp.asarray(x), eng.sharding)
     rng = np.random.default_rng(9)
-    keys = rng.integers(1, 2**63, N_SHARDS * LANES, dtype=np.uint64)
+    keys = rng.integers(1, 2**64 - 1, N_SHARDS * LANES, dtype=np.uint64)
     state, _, ok, dropped = eng.step(
         state, put(np.full(keys.size, OP_INSERT, np.int32)), put(keys),
         put(keys + 1))
     assert np.asarray(ok).all() and int(dropped) == 0
+    assert (eng.stats(state)["size"] > 0).all()
     rstep = eng.range_step(max_out=keys.size)
     ks = np.sort(np.unique(keys))
     los = np.zeros(keys.size, np.uint64)
     his = np.zeros(keys.size, np.uint64)
     valid = np.zeros(keys.size, bool)
-    los[0], his[0], valid[0] = 0, np.uint64(2**63), True      # everything
+    los[0], his[0], valid[0] = 0, np.uint64(2**64 - 1), True  # everything
     los[1], his[1], valid[1] = ks[10], ks[50], True           # 40 keys
     cnt = np.asarray(rstep(state, put(los), put(his), put(valid)))
     assert int(cnt[0]) == len(ks), cnt[0]

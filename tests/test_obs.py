@@ -383,10 +383,10 @@ class TestSpans:
         assert tr.spans == []
 
     @pytest.mark.parametrize("backend, present", [
-        ("det_skiplist", {"route", "find", "merge", "rebuild", "tombstone",
-                          "range_delete", "reclaim"}),
-        ("obs:tiered3/lru", {"route", "find", "update", "merge", "rebuild",
-                             "tombstone", "reclaim", "demote"}),
+        ("det_skiplist", {"route", "exchange", "find", "merge", "rebuild",
+                          "tombstone", "range_delete", "reclaim"}),
+        ("obs:tiered3/lru", {"route", "exchange", "find", "update", "merge",
+                             "rebuild", "tombstone", "reclaim", "demote"}),
     ])
     def test_engine_step_scopes_in_taxonomy(self, backend, present):
         eng = one_shard_engine(backend)
